@@ -2,8 +2,8 @@
 
 Workload: the serving_bench SARD triage stream (same trained CNN, same
 triage policy, 25% fog-corrupted), served through ``serve_sar_fleet``
-at ``P`` pools × ``SLOTS_PER_POOL`` slots for P in (1, 2, 4, 8) on a
-simulated 8-device host mesh.  8 × 64 = 512 concurrent decision slots
+at ``P`` pools × ``SLOTS_PER_POOL`` slots for P in (1, 2, 4, 8), as far
+as the process has devices.  8 × 64 = 512 concurrent decision slots
 — 16× the single-pool serving_bench workload.
 
 Weak scaling: the request count grows with P (``REQS_PER_POOL`` per
@@ -46,25 +46,22 @@ The 4-pool point carries the ROADMAP item-1 acceptance gates (enforced
 by ``regress.py --baseline benchmarks/baseline_fleet.json``): mesh
 speedup ≥ 3× over one pool and scaling efficiency ≥ 0.7.
 
-Device bootstrap: the sweep needs 8 devices; when the process has
-fewer (the default CPU process exposes one) the bench re-runs itself
-in a subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count
-=8`` and reads the report back — so ``python -m benchmarks.run --only
-fleet_bench`` works from any process.
+Devices: the sweep runs in this process on the devices it finds and
+stops at the largest P they hold; it starts no child process.  On CPU,
+give the process 8 host devices before it starts:
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 Outputs: repo-root ``BENCH_fleet.json`` (full report), a ``fleet`` key
 merged into ``BENCH_serving.json`` (kept across serving_bench rewrites)
 and one ``fleet_bench`` record in ``BENCH_history.jsonl``.
 
-Run: PYTHONPATH=src python -m benchmarks.run --only fleet_bench
+Run: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+     PYTHONPATH=src python -m benchmarks.run --only fleet_bench
 """
 
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
 import time
 from pathlib import Path
 
@@ -75,8 +72,13 @@ SERVING_JSON = ROOT / "BENCH_serving.json"
 POOLS = (1, 2, 4, 8)
 SLOTS_PER_POOL = 64
 REQS_PER_POOL = 384
-N_DEVICES = 8
 CORRUPT_FRAC = 0.25
+
+
+def _pool_counts() -> tuple[int, ...]:
+    """The sweep points this process's devices can hold."""
+    import jax
+    return tuple(p for p in POOLS if p <= len(jax.devices()))
 
 
 def _policy():
@@ -121,7 +123,7 @@ def _persist_ticks(n_pools: int, tick_log: list[dict]) -> None:
     rerun offline without redoing the sweep."""
     d = Path(__file__).parent.parent / "artifacts" / "fleet"
     d.mkdir(parents=True, exist_ok=True)
-    mode = "w" if n_pools == POOLS[0] else "a"
+    mode = "w" if n_pools == 1 else "a"
     with open(d / "ticks.jsonl", mode) as f:
         for i, t in enumerate(tick_log):
             f.write(json.dumps({"pools": n_pools, "tick": i,
@@ -169,7 +171,8 @@ def _report() -> dict:
     from benchmarks.serving_bench import trained_params
     cfg = SarCnnConfig()
     params = trained_params(cfg)
-    sweep = {str(p): _measure(params, cfg, p) for p in POOLS}
+    pools = _pool_counts()
+    sweep = {str(p): _measure(params, cfg, p) for p in pools}
 
     # calibrate the per-pool tick-cost model on the 1-pool warm run,
     # then evaluate every sweep point's tick log under it (see module
@@ -177,7 +180,7 @@ def _report() -> dict:
     a, b = _fit_tick_model(sweep["1"]["tick_log"])
     base_wall = sweep["1"]["decisions_per_s_warm"]
     base_mesh = None
-    for p in POOLS:
+    for p in pools:
         rec = sweep[str(p)]
         t_mesh = _mesh_time_s(rec["tick_log"], a, b)
         rec["mesh_time_s"] = t_mesh
@@ -199,13 +202,13 @@ def _report() -> dict:
                                   if max_trips > 0 else 0.0)
         _persist_ticks(p, rec["tick_log"])
         del rec["tick_log"]                 # raw log stays out of JSON
-    return {
+    report = {
         "workload": {
-            "pools": list(POOLS),
+            "pools": list(pools),
             "slots_per_pool": SLOTS_PER_POOL,
             "requests_per_pool": REQS_PER_POOL,
             "corrupt_frac": CORRUPT_FRAC,
-            "n_devices": N_DEVICES,
+            "n_devices": pools[-1],
             "scaling": "weak (requests grow with P)",
         },
         "latency_model": {
@@ -217,17 +220,20 @@ def _report() -> dict:
                       "(a + b * max_p trips[p])",
         },
         "pools": sweep,
-        "speedup_4pools": sweep["4"]["speedup"],
-        "scaling_efficiency_4pools": sweep["4"]["scaling_efficiency"],
-        "speedup_8pools": sweep["8"]["speedup"],
-        "scaling_efficiency_8pools": sweep["8"]["scaling_efficiency"],
-        "straggler_share_8pools": sweep["8"]["straggler_share"],
     }
+    for p in ("4", "8"):
+        if p in sweep:
+            report[f"speedup_{p}pools"] = sweep[p]["speedup"]
+            report[f"scaling_efficiency_{p}pools"] = \
+                sweep[p]["scaling_efficiency"]
+    if "8" in sweep:
+        report["straggler_share_8pools"] = sweep["8"]["straggler_share"]
+    return report
 
 
 def _rows(report: dict) -> list[tuple[str, float, str]]:
     out = []
-    for p in POOLS:
+    for p in report["workload"]["pools"]:
         rec = report["pools"][str(p)]
         us = rec["cold_wall_s"] * 1e6 / max(rec["decisions"], 1)
         out.append((f"fleet_sar_{p}pool", us,
@@ -242,12 +248,12 @@ def _rows(report: dict) -> list[tuple[str, float, str]]:
                     f"samples={rec['mean_samples_per_decision']:.2f};"
                     f"flagged={rec['flag_fraction']:.3f};"
                     f"gang={rec['gang']}"))
+    scaling = "".join(
+        f"speedup_{p}pools={report[f'speedup_{p}pools']:.2f}x;"
+        f"eff_{p}pools={report[f'scaling_efficiency_{p}pools']:.2f};"
+        for p in (4, 8) if f"speedup_{p}pools" in report)
     out.append(("fleet_sar_scaling", 0.0,
-                f"speedup_4pools={report['speedup_4pools']:.2f}x;"
-                f"eff_4pools={report['scaling_efficiency_4pools']:.2f};"
-                f"speedup_8pools={report['speedup_8pools']:.2f}x;"
-                f"eff_8pools={report['scaling_efficiency_8pools']:.2f};"
-                f"model=a+b*trips,a="
+                scaling + "model=a+b*trips,a="
                 f"{report['latency_model']['a_s_per_pool_tick']*1e3:.2f}"
                 f"ms,b="
                 f"{report['latency_model']['b_s_per_trip']*1e3:.2f}ms"))
@@ -271,13 +277,13 @@ def _merge_into_serving_json(report: dict) -> None:
                        "per_pool_syncs_per_decision")}
                   for p in report["pools"]},
         "latency_model": report["latency_model"],
-        "speedup_4pools": report["speedup_4pools"],
-        "scaling_efficiency_4pools": report["scaling_efficiency_4pools"],
+        "speedup_4pools": report.get("speedup_4pools"),
+        "scaling_efficiency_4pools": report.get("scaling_efficiency_4pools"),
     }
     SERVING_JSON.write_text(json.dumps(prev, indent=2, sort_keys=True))
 
 
-def _bench_here() -> list[tuple[str, float, str]]:
+def bench() -> list[tuple[str, float, str]]:
     report = _report()
     BENCH_JSON.write_text(json.dumps(report, indent=2, sort_keys=True))
     _merge_into_serving_json(report)
@@ -285,46 +291,13 @@ def _bench_here() -> list[tuple[str, float, str]]:
     history.record("fleet_bench",
                    {"pools": report["pools"],
                     "latency_model": report["latency_model"],
-                    "speedup_4pools": report["speedup_4pools"],
+                    "speedup_4pools": report.get("speedup_4pools"),
                     "scaling_efficiency_4pools":
-                        report["scaling_efficiency_4pools"]},
+                        report.get("scaling_efficiency_4pools")},
                    path=ROOT / "BENCH_history.jsonl")
     return _rows(report)
 
 
-def _bench_subprocess() -> list[tuple[str, float, str]]:
-    """Re-run the sweep in a child with 8 forced host devices."""
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count"
-            f"={N_DEVICES}").strip()
-    src = str(ROOT / "src")
-    env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
-                         if env.get("PYTHONPATH") else src)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.fleet_bench"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=3000)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"fleet_bench subprocess failed:\n{proc.stdout}\n"
-            f"{proc.stderr}")
-    return _rows(json.loads(BENCH_JSON.read_text()))
-
-
-def bench() -> list[tuple[str, float, str]]:
-    import jax
-    if len(jax.devices()) < N_DEVICES:
-        return _bench_subprocess()
-    return _bench_here()
-
-
 if __name__ == "__main__":
-    import jax
-    if len(jax.devices()) < N_DEVICES:
-        rows = _bench_subprocess()
-    else:
-        rows = _bench_here()
-    for row in rows:
+    for row in bench():
         print(",".join(str(x) for x in row))

@@ -73,6 +73,8 @@ def main() -> None:
                     help="skip appending run records to "
                          "BENCH_history.jsonl")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = []

@@ -31,51 +31,19 @@ from pathlib import Path
 import jax
 import msgpack
 import numpy as np
-
-try:  # zstd preferred; fall back to stdlib zlib when absent.
-    import zstandard
-except ImportError:  # pragma: no cover - environment dependent
-    zstandard = None
+import zstandard
 
 from repro.utils.trees import flatten_dict, unflatten_dict
 
 _MANIFEST = "manifest.msgpack"
 _SHARD = "shard_0.bin.zst"
-_CODEC = "zstd" if zstandard is not None else "zlib"
 
 
-class _ZlibWriter:
-    """Minimal stream_writer-compatible zlib compressor."""
-
-    def __init__(self, f, level: int = 3):
-        self._f = f
-        self._c = zlib.compressobj(level)
-
-    def write(self, buf: bytes) -> None:
-        self._f.write(self._c.compress(buf))
-
-    def flush(self, *_args) -> None:
-        self._f.write(self._c.flush(zlib.Z_SYNC_FLUSH))
-
-    def close(self) -> None:
-        self._f.write(self._c.flush())
-
-
-def _shard_writer(f, codec: str):
-    if codec == "zstd":
-        return zstandard.ZstdCompressor(level=3).stream_writer(f)
-    return _ZlibWriter(f)
-
-
-def _shard_decompress(data: bytes, codec: str) -> bytes:
-    if codec == "zstd":
-        if zstandard is None:
-            raise ImportError(
-                "checkpoint was written with zstd but the 'zstandard' "
-                "package is not installed")
-        return zstandard.ZstdDecompressor().decompress(
-            data, max_output_size=1 << 38)
-    return zlib.decompress(data)
+def _shard_decompress(data: bytes) -> bytes:
+    """Decompress a stream-written shard.  A frame written through
+    ``stream_writer`` carries no content size, so decompress it as a
+    stream rather than sizing an output buffer up front."""
+    return zstandard.ZstdDecompressor().decompressobj().decompress(data)
 
 
 def save(ckpt_dir: str | Path, step: int, tree, keep_last: int = 3) -> Path:
@@ -89,10 +57,10 @@ def save(ckpt_dir: str | Path, step: int, tree, keep_last: int = 3) -> Path:
     tmp.mkdir(parents=True)
 
     flat = flatten_dict(tree)
-    manifest = {"step": step, "codec": _CODEC, "leaves": {}}
+    manifest = {"step": step, "codec": "zstd", "leaves": {}}
     offset = 0
     with open(tmp / _SHARD, "wb") as f:
-        writer = _shard_writer(f, _CODEC)
+        writer = zstandard.ZstdCompressor(level=3).stream_writer(f)
         for path, leaf in sorted(flat.items()):
             arr = np.asarray(leaf)
             buf = arr.tobytes()
@@ -141,8 +109,7 @@ def restore(ckpt_dir: str | Path, step: int | None = None):
             raise FileNotFoundError(f"no checkpoints in {ckpt_dir}")
     d = ckpt_dir / f"step_{step:010d}"
     manifest = msgpack.unpackb((d / _MANIFEST).read_bytes())
-    raw = _shard_decompress((d / _SHARD).read_bytes(),
-                            manifest.get("codec", "zstd"))
+    raw = _shard_decompress((d / _SHARD).read_bytes())
     flat = {}
     for path, meta in manifest["leaves"].items():
         buf = raw[meta["offset"]:meta["offset"] + meta["nbytes"]]

@@ -27,6 +27,8 @@ benchmarks/fig10_selection.py.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -45,8 +47,12 @@ def lfsr_next(state: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(lsb == 1, shifted ^ jnp.uint32(LFSR_MASK), shifted)
 
 
+@functools.partial(jax.jit, static_argnames=("num",))
 def lfsr_states(seed: int | jnp.ndarray, num: int) -> jnp.ndarray:
-    """Generate ``num`` successive LFSR states from ``seed``. -> [num] u32."""
+    """Generate ``num`` successive LFSR states from ``seed``. -> [num] u32.
+
+    Jitted so that a call outside a trace reuses one executable per
+    ``num`` (an eager ``lax.scan`` recompiles on every call)."""
     seed = jnp.asarray(seed, jnp.uint32) & jnp.uint32(0xFFFF)
     seed = jnp.where(seed == 0, jnp.uint32(0xACE1), seed)  # 0 is a fixed point
 

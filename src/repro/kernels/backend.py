@@ -1,67 +1,40 @@
 """Backend selection for the Pallas kernels: compile on TPU, interpret
 elsewhere.
 
-Every kernel wrapper in this package takes ``interpret: bool | None``.
-``None`` (the default everywhere) resolves through ``interpret_default``:
-Pallas kernels COMPILE when the active JAX backend is a real TPU and
-fall back to interpret mode otherwise (CPU CI, local dev), so TPU runs
-stop paying the interpreter cost without any call-site changes.
+Every public kernel entry point (kernels/ops.py) takes
+``interpret: bool | None`` and resolves it with ``resolve_interpret``
+BEFORE entering the kernel's ``jax.jit``, so the static argument the
+jit sees is always a concrete bool and the resolved mode is part of the
+compile cache key.  Resolution, highest first:
 
-Resolution precedence, highest first:
+  1. **Per-call argument** — an explicit ``interpret=True/False``.
+  2. **Scoped override** — ``with interpret_override(False): ...`` pins
+     the mode for every call left at ``None`` in the dynamic extent.
+     The chip-compile tests use it to lower whole serving programs for
+     a described TPU from a CPU process.
+  3. **Backend auto-detect** — interpret unless the active JAX backend
+     is a TPU.
 
-  1. **Per-call argument** — an explicit ``interpret=True/False`` passed
-     to a kernel wrapper always wins.  The shard_map-native decision
-     kernel resolves the flag *once* at the wrapper level and passes the
-     concrete bool into every shard, so all shards of one call lower
-     identically regardless of ambient state.
-  2. **Scoped override** — ``with interpret_override(True/False): ...``
-     pins the mode for every kernel call (with ``interpret=None``) in
-     the dynamic extent.  Used to force compile/interpret per shard or
-     per benchmark arm without threading a flag through every layer.
-  3. **Environment** — ``REPRO_PALLAS_INTERPRET``:
-     ``1``/``true`` force interpret everywhere (debugging a kernel on
-     TPU, double-checking a miscompile); ``0``/``false`` force compiled
-     mode (Pallas-on-Mosaic-CPU experiments); unset/``auto`` falls
-     through.
-  4. **Backend auto-detect** — interpret unless the active JAX backend
-     is a real TPU.
-
-This module is import-cycle-free on purpose: the kernel modules
-(bayes_mvm, cim_mvm, clt_grng_kernel, decision_kernel) import it, and
-``kernels/ops.py`` re-exports ``interpret_default`` as the public
-helper.
+This module is import-cycle-free on purpose: the kernel wrappers in
+``kernels/ops.py`` import it.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 
 import jax
-
-_ENV = "REPRO_PALLAS_INTERPRET"
-_TRUE = ("1", "true", "yes", "on")
-_FALSE = ("0", "false", "no", "off")
 
 _local = threading.local()
 
 
 def interpret_default() -> bool:
-    """Resolve the interpret-mode default for a Pallas kernel call.
-
-    Scoped ``interpret_override`` first, then the env override
-    (``REPRO_PALLAS_INTERPRET``), then backend auto-detection:
-    interpret unless running on real TPU hardware.
-    """
+    """The scoped ``interpret_override`` if set, else True unless the
+    active JAX backend is a TPU."""
     override = getattr(_local, "override", None)
     if override is not None:
         return override
-    raw = os.environ.get(_ENV, "auto").strip().lower()
-    if raw in _TRUE:
-        return True
-    if raw in _FALSE:
-        return False
     return jax.default_backend() != "tpu"
 
 
@@ -71,10 +44,9 @@ def interpret_override(value: bool | None):
 
     ``True``/``False`` force the mode for every kernel invoked with
     ``interpret=None``; ``None`` restores auto resolution.  Overrides
-    nest (innermost wins) and are thread-local, so concurrent benches
-    don't bleed into each other.  An explicit per-call ``interpret=``
-    argument still beats the override — see the module docstring for
-    the full precedence.
+    nest (innermost wins) and are thread-local.  The override is read
+    at trace time: lower a FRESH jitted function inside it, since an
+    already-traced one keeps the mode it was traced with.
     """
     prev = getattr(_local, "override", None)
     _local.override = None if value is None else bool(value)

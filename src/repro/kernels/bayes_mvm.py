@@ -47,7 +47,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.clt_grng import GRNGConfig
 from repro.core.quant import QuantConfig
-from repro.kernels.backend import resolve_interpret
 from repro.kernels.clt_grng_kernel import (_device_current, _gauss_of, _hash3,
                                            _read_noise)
 
@@ -202,16 +201,15 @@ def _pad2(a, m0, m1):
 def bayes_mvm_pallas(x, mu, sigma, sel, fs, cfg: GRNGConfig,
                      qcfg: QuantConfig | None = None, mode: str = "rank16",
                      row0: int = 0, col0: int = 0, sample0: int = 0,
-                     bb: int = 128, bk: int = 128, bn: int = 128,
-                     interpret: bool | None = None):
+                     bb: int = 128, bk: int = 128, bn: int = 128, *,
+                     interpret: bool):
     """Fused Bayesian head. x:[B,K], µ/σ:[K,N], sel:[R,16], fs:[1,2].
 
     Returns [R, B, N] float32 logit samples.  Zero-padding is safe: σ and
     µ pads are zero so padded rows/cols contribute nothing.
-    ``interpret=None`` auto-detects the backend (kernels/backend.py):
-    compiled on TPU, interpreted elsewhere.
+    ``interpret`` is a concrete bool, resolved by the caller
+    (kernels/ops.py) so that it is part of the jit cache key.
     """
-    interpret = resolve_interpret(interpret)
     b, kdim = x.shape
     _, n = mu.shape
     r = sel.shape[0]

@@ -21,7 +21,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import QuantConfig
-from repro.kernels.backend import resolve_interpret
 
 
 def _cim_kernel(x_ref, w_ref, fs_ref, gain_ref, off_ref, o_ref, acc_ref, *,
@@ -71,8 +70,8 @@ def _cim_kernel(x_ref, w_ref, fs_ref, gain_ref, off_ref, o_ref, acc_ref, *,
                                              "interpret"))
 def cim_mvm_pallas(x, w, fs, qcfg: QuantConfig,
                    col_gain=None, col_offset=None,
-                   bb: int = 128, bk: int = 128, bn: int = 128,
-                   interpret: bool | None = None):
+                   bb: int = 128, bk: int = 128, bn: int = 128, *,
+                   interpret: bool):
     """Chunked-ADC MVM. x:[B,K], w:[K,N], fs:[1,1] -> [B,N] float32.
 
     K must be a multiple of qcfg.chunk (the physical tile depth); B and N
@@ -83,8 +82,9 @@ def cim_mvm_pallas(x, w, fs, qcfg: QuantConfig,
     col_gain/col_offset: optional [N] per-column ADC gain and offset
     (offset in LSB units) — the nonideal chip-instance path.  Omitted =
     ideal ADC (bit-identical to the previous behaviour).
+    ``interpret`` is a concrete bool, resolved by the caller
+    (kernels/ops.py) so that it is part of the jit cache key.
     """
-    interpret = resolve_interpret(interpret)
     b, kdim = x.shape
     n = w.shape[1]
     assert kdim % qcfg.chunk == 0, "K must be chunk-aligned (tile depth)"

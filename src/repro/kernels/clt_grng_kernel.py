@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.clt_grng import GRNGConfig
-from repro.kernels.backend import resolve_interpret
 
 _C1 = 0x9E3779B9
 _C2 = 0x85EBCA6B
@@ -51,18 +50,24 @@ def _hash3(k, n, j, seed: int):
     return h
 
 
+def _small_u32_to_f32(v):
+    """Exact float of a uint32 below 2**31, cast through int32: Mosaic
+    has no direct uint32 -> float32 conversion."""
+    return v.astype(jnp.int32).astype(jnp.float32)
+
+
 def _gauss_of(h):
     """CLT-of-bytes normal surrogate (core.hashing.gaussianish, inlined)."""
-    b0 = (h & jnp.uint32(0xFF)).astype(jnp.float32)
-    b1 = ((h >> jnp.uint32(8)) & jnp.uint32(0xFF)).astype(jnp.float32)
-    b2 = ((h >> jnp.uint32(16)) & jnp.uint32(0xFF)).astype(jnp.float32)
+    b0 = _small_u32_to_f32(h & jnp.uint32(0xFF))
+    b1 = _small_u32_to_f32((h >> jnp.uint32(8)) & jnp.uint32(0xFF))
+    b2 = _small_u32_to_f32((h >> jnp.uint32(16)) & jnp.uint32(0xFF))
     return (b0 + b1 + b2 - 382.5) * (1.0 / 127.99316)
 
 
 def _device_current(rows, cols, j: int, cfg: GRNGConfig):
     """Virtual device current I(k, n, j) for a coordinate block."""
     h = _hash3(rows, cols, j, cfg.seed)
-    bit = ((h >> jnp.uint32(31)) & jnp.uint32(1)).astype(jnp.float32)
+    bit = _small_u32_to_f32((h >> jnp.uint32(31)) & jnp.uint32(1))
     out = cfg.i_lo + cfg.delta_i * bit + cfg.gamma * _gauss_of(h)
     if cfg.imprint:                          # aged-die twin (hw/aging)
         out = out + cfg.imprint * _gauss_of(
@@ -102,15 +107,15 @@ def _grng_kernel(sel_ref, out_ref, *, cfg: GRNGConfig, bk: int, bn: int,
     "interpret"))
 def grng_eps_pallas(sel: jnp.ndarray, cfg: GRNGConfig, n_rows: int,
                     n_cols: int, row0: int = 0, col0: int = 0,
-                    sample0: int = 0, bk: int = 256, bn: int = 256,
-                    interpret: bool | None = None) -> jnp.ndarray:
+                    sample0: int = 0, bk: int = 256, bn: int = 256, *,
+                    interpret: bool) -> jnp.ndarray:
     """ε block via Pallas. sel: [R, 16] float32 -> [R, n_rows, n_cols].
 
     ``sample0``: absolute index of sel[0] in the selection stream — only
     read (for the noise hash) when ``cfg.read_sigma > 0``.
-    ``interpret=None`` auto-detects the backend (kernels/backend.py).
+    ``interpret`` is a concrete bool, resolved by the caller
+    (kernels/ops.py) so that it is part of the jit cache key.
     """
-    interpret = resolve_interpret(interpret)
     r = sel.shape[0]
     pad_k = (-n_rows) % bk
     pad_n = (-n_cols) % bn

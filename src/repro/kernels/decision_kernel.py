@@ -60,7 +60,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.clt_grng import GRNGConfig
-from repro.kernels.backend import resolve_interpret
 from repro.kernels.clt_grng_kernel import _gauss_of, _hash3
 
 _NEG = -1.0e30            # masked-logit fill: exp underflows to exactly 0
@@ -70,10 +69,13 @@ def _mix_logits(m_blk, sel, y_mu, x_sigma, x_sigsq, sidx, rows, *,
                 cfg: GRNGConfig, i, k, bb, bn, n: int):
     """[R, bb, bn] logit samples for one (batch, column) block — the
     in-VMEM replica of core.sampling.mix_samples, padded cols → -1e30."""
-    # per-slot mixing: [bb,R,16] × [bb,bn,16] → [bb,R,bn] (batched MXU)
+    # per-slot mixing: [bb,R,16] × [bb,bn,16] → [bb,R,bn] (batched MXU).
+    # HIGHEST: Mosaic's default contracts f32 in one bf16 pass, which
+    # moves the per-sample probabilities by ~2e-3 on a TPU v5e.
     mix = jax.lax.dot_general(
         jnp.transpose(sel, (1, 0, 2)), m_blk,
         (((2,), (2,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     mix = jnp.transpose(mix, (1, 0, 2))                  # [R, bb, bn]
     num = mix - cfg.sum_mean * x_sigma[None]
@@ -164,8 +166,8 @@ def _round_up(v: int, m: int) -> int:
     "cfg", "bb", "bn", "interpret"))
 def decision_stats_pallas(y_mu, x_sigma, m, sel, cfg: GRNGConfig,
                           x_sigsq=None, sample_idx=None, mask=None,
-                          rows=None, bb: int = 0, bn: int = 128,
-                          interpret: bool | None = None) -> dict:
+                          rows=None, bb: int = 0, bn: int = 128, *,
+                          interpret: bool) -> dict:
     """Fused decision-statistic deltas for one escalation round.
 
     y_mu/x_sigma: [B, N]; m: [B, N, 16] (``activation_basis``);
@@ -181,10 +183,10 @@ def decision_stats_pallas(y_mu, x_sigma, m, sel, cfg: GRNGConfig,
     Returns the per-round deltas, already masked (inactive rows are 0):
     ``{sum_p [B,N] f32, sum_psq [B,N], sum_ent [B], sum_entsq [B]}`` —
     add them to running stats (``kernels.ops.decision_update`` does,
-    together with the ``n`` count).  ``interpret=None`` auto-detects
-    the backend (kernels/backend.py).
+    together with the ``n`` count).  ``interpret`` is a concrete bool,
+    resolved by the caller (kernels/ops.py) so that it is part of the
+    jit cache key.
     """
-    interpret = resolve_interpret(interpret)
     b, n = y_mu.shape
     if sel.ndim == 2:
         sel = jnp.broadcast_to(sel[:, None, :], (sel.shape[0], b, 16))
@@ -263,7 +265,7 @@ def decision_stats_pallas(y_mu, x_sigma, m, sel, cfg: GRNGConfig,
 def decision_stats_sharded(y_mu, x_sigma, m, sel, cfg: GRNGConfig, *,
                            mesh, axis: str, x_sigsq=None, sample_idx=None,
                            mask=None, rows=None, bb: int = 0, bn: int = 128,
-                           interpret: bool | None = None) -> dict:
+                           interpret: bool) -> dict:
     """Shard_map-native fused decision update over the slot (batch) axis.
 
     Each shard runs its own ``decision_stats_pallas`` grid on its local
@@ -275,16 +277,12 @@ def decision_stats_sharded(y_mu, x_sigma, m, sel, cfg: GRNGConfig, *,
     read-noise stream; default ``arange(B)`` so shard k hashes with its
     true global offsets instead of local 0..B/k-1).
 
-    ``interpret`` is resolved ONCE here (per-call arg > scoped override
-    > env > backend auto-detect — see kernels/backend.py) and passed as
-    a concrete bool into every shard, so all shards lower identically.
+    ``interpret`` is the concrete bool the caller resolved
+    (kernels/ops.py); every shard lowers with it.
 
     Requires ``B % mesh.shape[axis] == 0``; callers fall back to the
     unsharded kernel otherwise.
     """
-    from repro.launch.mesh import shard_map_compat
-
-    interpret = resolve_interpret(interpret)
     b, _ = y_mu.shape
     shards = mesh.shape[axis]
     if b % shards:
@@ -327,6 +325,6 @@ def decision_stats_sharded(y_mu, x_sigma, m, sel, cfg: GRNGConfig, *,
         args = (y_mu, x_sigma, m, sel, mask)
         in_specs = (P(axis), P(axis), P(axis), P(None, axis), P(axis))
 
-    fn = shard_map_compat(local, mesh=mesh, in_specs=in_specs,
-                          out_specs=P(axis))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(axis), check_vma=False)
     return fn(*args)

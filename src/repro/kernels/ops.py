@@ -1,9 +1,10 @@
 """Public jit'd entry points for the kernels package.
 
 These wrappers own host-side concerns: selection-table generation,
-ADC full-scale calibration, dtype plumbing, and the interpret-mode
-default (``interpret_default``: compile on TPU, interpret elsewhere,
-env-overridable — kernels/backend.py).  They are the drop-in
+ADC full-scale calibration, dtype plumbing, and the interpret mode.
+``interpret=None`` resolves here, outside every kernel's ``jax.jit``
+(compile on TPU, interpret elsewhere — kernels/backend.py), so the
+jitted kernels only ever see a concrete bool.  They are the drop-in
 counterparts of the pure-jnp paths in core/sampling.py and core/cim.py,
 asserted allclose in tests/test_kernels.py.
 """
@@ -14,8 +15,7 @@ import jax.numpy as jnp
 
 from repro.core import clt_grng as g
 from repro.core.quant import QuantConfig, adc_full_scale
-# Public backend helper (implemented cycle-free in kernels/backend.py).
-from repro.kernels.backend import interpret_default  # noqa: F401
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.bayes_mvm import bayes_mvm_pallas
 from repro.kernels.cim_mvm import cim_mvm_pallas
 from repro.kernels.clt_grng_kernel import grng_eps_pallas
@@ -32,7 +32,7 @@ def grng_eps(cfg: g.GRNGConfig, n_rows: int, n_cols: int, num_samples: int,
     bn = min(256, max(128, n_cols))
     return grng_eps_pallas(
         sel, cfg, n_rows, n_cols, row0=row0, col0=col0, sample0=sample0,
-        bk=bk, bn=bn, interpret=interpret)
+        bk=bk, bn=bn, interpret=resolve_interpret(interpret))
 
 
 def bayes_head_mvm(x: jnp.ndarray, mu_prime: jnp.ndarray, sigma: jnp.ndarray,
@@ -66,7 +66,8 @@ def bayes_head_mvm(x: jnp.ndarray, mu_prime: jnp.ndarray, sigma: jnp.ndarray,
         fs = jnp.zeros((1, 2), jnp.float32)
     return bayes_mvm_pallas(
         x, mu_prime, sigma, sel, fs, cfg, qcfg=qcfg, mode=mode,
-        row0=row0, col0=col0, sample0=sample0, interpret=interpret)
+        row0=row0, col0=col0, sample0=sample0,
+        interpret=resolve_interpret(interpret))
 
 
 def decision_update(stats: dict, abasis: dict, sel: jnp.ndarray,
@@ -98,6 +99,7 @@ def decision_update(stats: dict, abasis: dict, sel: jnp.ndarray,
     numerics agree to fp32 tolerance (online vs one-shot logsumexp
     reduction order).
     """
+    interpret = resolve_interpret(interpret)
     if shard is not None:
         mesh, axis = shard
         delta = decision_stats_sharded(
@@ -139,7 +141,8 @@ def cim_matmul(x: jnp.ndarray, w: jnp.ndarray, qcfg: QuantConfig,
                interpret: bool | None = None) -> jnp.ndarray:
     """Deterministic chunked-ADC CIM matmul (µ-only subarray)."""
     fs = _measured_full_scale(x, w, qcfg).reshape(1, 1)
-    return cim_mvm_pallas(x, w, fs, qcfg, interpret=interpret)
+    return cim_mvm_pallas(x, w, fs, qcfg,
+                          interpret=resolve_interpret(interpret))
 
 
 def cim_matmul_nonideal(x: jnp.ndarray, w: jnp.ndarray, qcfg: QuantConfig,
@@ -155,4 +158,5 @@ def cim_matmul_nonideal(x: jnp.ndarray, w: jnp.ndarray, qcfg: QuantConfig,
     """
     fs = _measured_full_scale(x, w, qcfg).reshape(1, 1)
     return cim_mvm_pallas(x, w, fs, qcfg, col_gain=col_gain,
-                          col_offset=col_offset, interpret=interpret)
+                          col_offset=col_offset,
+                          interpret=resolve_interpret(interpret))

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCHS, SHAPES, cells_for, get_config
 from repro.launch.hlo_analysis import analyze as hlo_analyze
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (jit_decode_step, jit_prefill_step,
                                 jit_train_step)
 from repro.obs.log import get_logger
@@ -138,7 +138,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     mesh_name = "pod2x16x16" if multi_pod else "pod16x16"
     t0 = time.perf_counter()
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             jitted, abstracts, _, cfg2 = jit_train_step(
                 cfg, mesh, AdamWConfig(master_weights=master_weights),

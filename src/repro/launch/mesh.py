@@ -1,4 +1,4 @@
-"""Production mesh construction + jax version compatibility shims.
+"""Production mesh construction.
 
 A function, not a module-level constant, so importing this module never
 touches jax device state.  Single pod: 16×16 = 256 chips (TPU v5e pod);
@@ -6,81 +6,26 @@ multi-pod: 2 × 256 = 512 chips with the leading 'pod' axis crossing the
 inter-pod (DCN-class) boundary — gradient reduction and nothing else
 should travel on it.
 
-Version compatibility: the repo targets the current jax API
-(``jax.sharding.AxisType``, ``jax.set_mesh``, ``jax.shard_map``) but
-must run on older installs (0.4.x) where those names do not exist.
-Every mesh construction and mesh-context entry in the codebase goes
-through the ``make_mesh_compat`` / ``mesh_context`` / ``shard_map_compat``
-shims below so the fallback lives in exactly one place.
+Every mesh here has Auto axes: shardings are propagated by the compiler
+from ``with_sharding_constraint`` hints, and explicit per-device code
+goes through ``jax.shard_map``.  Activate a mesh with ``jax.set_mesh``.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
 
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` on new jax, nothing on old jax (whose
-    meshes are implicitly fully-auto)."""
-    if HAS_AXIS_TYPE:
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
-def make_mesh_compat(shape: tuple, axes: tuple):
-    """jax.make_mesh with Auto axis types where the install supports it."""
-    return jax.make_mesh(shape, axes, **axis_types_kwargs(len(axes)))
-
-
-def mesh_context(mesh):
-    """Context manager activating ``mesh`` for jit/wsc spec resolution.
-
-    New jax: ``jax.set_mesh`` (abstract-mesh aware).  Old jax: the Mesh
-    object itself is a context manager installing the legacy global
-    mesh, which is what ``with_sharding_constraint`` with bare
-    PartitionSpecs resolves against.
-    """
-    if HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext(mesh)
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map (check_vma off) or the jax.experimental fallback
-    (check_rep off — same semantics, pre-rename)."""
-    if HAS_SHARD_MAP:
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
-def abstract_mesh_or(mesh=None):
-    """The ambient abstract mesh on new jax; ``mesh`` (or the legacy
-    global physical mesh) on old jax."""
-    if HAS_ABSTRACT_MESH:
-        return jax.sharding.get_abstract_mesh()
-    if mesh is not None:
-        return mesh
-    from jax.interpreters.pxla import thread_resources
-    env_mesh = thread_resources.env.physical_mesh
-    return None if env_mesh.empty else env_mesh
+def make_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` over the first prod(shape) devices, Auto axes."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int | None = None):
@@ -91,4 +36,4 @@ def make_debug_mesh(n_devices: int | None = None):
         if n % cand == 0:
             model = cand
             break
-    return make_mesh_compat((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

@@ -88,6 +88,8 @@ def main() -> None:
                     help="capture a jax.profiler (XLA) trace of the "
                          "mission into DIR (TensorBoard-loadable)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.mission import (MissionPolicy, UavConfig, WorldConfig,
                                fly_mission, trained_detector)

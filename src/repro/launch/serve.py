@@ -24,7 +24,7 @@ Usage:
       --requests 128 --corrupt-frac 0.25 --corruption fog
 
 Multi-device note: wrap engine construction + run in
-``mesh_context(make_debug_mesh())`` and pass a mesh-hinted config to
+``jax.set_mesh(make_debug_mesh())`` and pass a mesh-hinted config to
 shard the pool batch across 'data' — the engine's jitted pool updates
 are ordinary jit calls and follow the ambient mesh.
 """
@@ -335,9 +335,9 @@ def serve_sar_fleet(*, n_requests: int = 256, n_pools: int = 4,
     ``n_pools`` complete serving pools tiled over a 1-D ``("pool",)``
     device mesh behind a least-loaded admission router; each fleet tick
     runs ONE shard_map'd gang round for every pool (``gang=None``
-    auto-enables it when the process has >= n_pools devices — use
-    XLA_FLAGS=--xla_force_host_platform_device_count=N or ``--mesh N``
-    to simulate a mesh on CPU).  Verdicts are bit-identical to
+    auto-enables it when the process has >= n_pools devices; on CPU,
+    set XLA_FLAGS=--xla_force_host_platform_device_count=N before the
+    process starts).  Verdicts are bit-identical to
     ``serve_sar`` pools fed the same admission sequences; the summary
     is the exact sum of the per-pool reports (energy, telemetry,
     decisions) plus router stats (``routed_per_pool``,
@@ -552,12 +552,6 @@ def main() -> None:
                          "dispatch per tick when devices allow)")
     ap.add_argument("--slots-per-pool", type=int, default=32,
                     help="decode slots per fleet pool (with --pools)")
-    ap.add_argument("--mesh", type=int, default=None, metavar="N",
-                    help="simulate an N-device host mesh: re-execs the "
-                         "process with XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=N so "
-                         "--pools can gang-dispatch over a real device "
-                         "mesh on CPU")
     ap.add_argument("--corrupt-frac", type=float, default=0.0)
     ap.add_argument("--corruption", default="fog",
                     choices=("fog", "frost", "motion", "snow"))
@@ -612,22 +606,8 @@ def main() -> None:
                          "and record compiled-cost analyses of the "
                          "engine's hot functions")
     args = ap.parse_args()
-    if args.mesh:
-        import os
-        import sys
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            # The import chain above already initialized the backend,
-            # which reads XLA_FLAGS exactly once — re-exec with the
-            # device-count flag in place (same argv; this branch is a
-            # no-op on the second pass).
-            env = dict(os.environ)
-            env["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count"
-                f"={args.mesh}").strip()
-            os.execvpe(sys.executable,
-                       [sys.executable, "-m", "repro.launch.serve",
-                        *sys.argv[1:]], env)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     policy = TriagePolicy(conf_threshold=args.conf_threshold,
                           mi_threshold=args.mi_threshold,
                           r_min=args.r_min, r_max=args.r_max)

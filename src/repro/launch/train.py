@@ -30,7 +30,7 @@ from repro.ckpt import AsyncCheckpointer, latest_step, restore
 from repro.configs import ARCHS, get_config
 from repro.data.tokens import TokenPipelineConfig, batch_at, stub_frames, \
     stub_image_embeds
-from repro.launch.mesh import make_debug_mesh, mesh_context
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.steps import make_train_step, mesh_hinted_config
 from repro.models.registry import get_api
 from repro.optim import AdamWConfig, init_opt_state
@@ -106,7 +106,7 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100, batch: int = 8,
     metrics_file = open(metrics_path, "a") if metrics_path else None
     history = []
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         for step in range(start, steps):
             monitor.start_step()
             data = build_batch(cfg, pipe_cfg, step)

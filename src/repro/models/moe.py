@@ -204,12 +204,11 @@ def make_sharded_moe(mesh, *, top_k: int, capacity_factor: float,
         wi_spec = P(None, "data", "model")
         wo_spec = P(None, "model", "data")
 
-    from repro.launch.mesh import shard_map_compat
-    smapped = shard_map_compat(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None), wi_spec, wi_spec,
                   wo_spec),
-        out_specs=(P(dp, None, None), P()),
+        out_specs=(P(dp, None, None), P()), check_vma=False,
     )
 
     def moe(x, router, wi, wg, wo):

@@ -175,10 +175,7 @@ def _model_ax(cfg: ModelConfig, dim: int):
 def _tp_ok(cfg: ModelConfig, d_in: int, d_out: int) -> bool:
     if not (cfg.explicit_tp and cfg.batch_axes and cfg.model_axis_size > 1):
         return False
-    from repro.launch.mesh import HAS_ABSTRACT_MESH, abstract_mesh_or
-    if not HAS_ABSTRACT_MESH:
-        return False  # explicit-TP is a current-jax-only perf path
-    mesh = abstract_mesh_or()
+    mesh = jax.sharding.get_abstract_mesh()
     data = mesh.shape.get("data", 1)
     return d_out % cfg.model_axis_size == 0 and d_in % data == 0
 
@@ -196,8 +193,7 @@ def _tp_linear(x, w, cfg: ModelConfig, kind: str):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import abstract_mesh_or, shard_map_compat
-    mesh = abstract_mesh_or()
+    mesh = jax.sharding.get_abstract_mesh()
     dp = tuple(cfg.batch_axes)
     lead = (dp,) + (None,) * (x.ndim - 2)
 
@@ -205,19 +201,19 @@ def _tp_linear(x, w, cfg: ModelConfig, kind: str):
         def body(x_loc, w_loc):
             w_full = lax.all_gather(w_loc, "data", axis=0, tiled=True)
             return x_loc @ w_full.astype(x_loc.dtype)
-        return shard_map_compat(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(*lead, None), P("data", "model")),
-            out_specs=P(*lead, "model"))(x, w)
+            out_specs=P(*lead, "model"), check_vma=False)(x, w)
 
     def body(x_loc, w_loc):
         w_full = lax.all_gather(w_loc, "data", axis=1, tiled=True)
         y = x_loc @ w_full.astype(x_loc.dtype)
         return lax.psum(y, "model")
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(*lead, "model"), P("model", "data")),
-        out_specs=P(*lead, None))(x, w)
+        out_specs=P(*lead, None), check_vma=False)(x, w)
 
 
 # ----------------------------------------------------------------------
@@ -374,10 +370,9 @@ def _mlp_apply(h, lp, cfg: ModelConfig):
         if cfg.batch_axes and cfg.model_axis_size > 1 and h.shape[1] > 1:
             # Perf I1: manual local dispatch - routing is batch-parallel,
             # so no dispatch collectives; one TP psum + FSDP gathers only.
-            from repro.launch.mesh import abstract_mesh_or
             from repro.models.moe import make_sharded_moe
             moe = make_sharded_moe(
-                abstract_mesh_or(), top_k=cfg.top_k,
+                jax.sharding.get_abstract_mesh(), top_k=cfg.top_k,
                 capacity_factor=cfg.capacity_factor,
                 n_experts=cfg.n_experts, dp_axes=tuple(cfg.batch_axes))
             return moe(h, lp["moe"]["router"].astype(h.dtype),

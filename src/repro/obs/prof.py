@@ -309,24 +309,16 @@ class CostRegistry:
 def trace_capture(log_dir: str | None):
     """Capture an XLA profiler trace into ``log_dir`` (TensorBoard /
     Perfetto-loadable).  ``None`` is a no-op so drivers can pass the
-    CLI flag straight through; failures to start (no profiler in this
-    jax build, port conflicts) degrade to a warning, never kill a
-    serving run."""
+    CLI flag straight through.  A profiler that cannot start raises: a
+    run asked to trace must not exit 0 without its trace."""
     if not log_dir:
         yield
         return
     import jax
     from repro.obs.log import get_logger
-    log = get_logger("prof")
-    started = False
-    try:
-        jax.profiler.start_trace(log_dir)
-        started = True
-    except Exception as e:  # noqa: BLE001
-        log.warning("jax.profiler trace capture unavailable", err=str(e))
+    jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
-        if started:
-            jax.profiler.stop_trace()
-            log.info("profiler trace written", dir=log_dir)
+        jax.profiler.stop_trace()
+        get_logger("prof").info("profiler trace written", dir=log_dir)

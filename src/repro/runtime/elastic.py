@@ -23,7 +23,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.launch.mesh import axis_types_kwargs
 from repro.sharding import specs as S
 
 
@@ -39,7 +38,8 @@ def make_elastic_mesh(devices=None, model_parallel: int | None = None) -> Mesh:
                 break
     assert n % model_parallel == 0
     arr = np.array(devices).reshape(n // model_parallel, model_parallel)
-    return Mesh(arr, ("data", "model"), **axis_types_kwargs(2))
+    return Mesh(arr, ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def shrink_mesh(mesh: Mesh, lost_devices: set) -> Mesh:

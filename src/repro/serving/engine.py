@@ -591,7 +591,8 @@ class SarServingEngine(_EngineBase):
                  tracer=None,
                  profiler: bool | StageProfiler = True,
                  slo=True,
-                 trace_pid: int = 0):
+                 trace_pid: int = 0,
+                 device=None):
         """``head``/``hcfg``: pre-deployed serving head + its config —
         the repro/hw chip-instance path (hw.calib.prepare_instance_head
         returns both; the rank-16 fast path below runs unchanged on the
@@ -611,7 +612,7 @@ class SarServingEngine(_EngineBase):
 
         ``slot_axis``: mesh axis name to shard the slot (pool batch)
         dimension over — construct and run the engine inside
-        ``mesh_context`` and admission scatters stay slot-local while
+        ``jax.set_mesh`` and admission scatters stay slot-local while
         every pool round executes data-parallel over the slots.
         ``mesh``: the jax.sharding.Mesh carrying ``slot_axis`` (default:
         captured from the ambient mesh context at construction).  When
@@ -636,9 +637,15 @@ class SarServingEngine(_EngineBase):
         for an owned tracker, a shared SloTracker (fleet), or False;
         like the profiler it is free at the decision level
         (tests/test_slo.py).
+
+        ``device``: the one device this engine's parameters, pool,
+        statistics and telemetry live on (None: JAX's default device,
+        uncommitted).  A fleet binds pool ``p`` to device ``p``.
         """
         super().__init__(n_slots, policy, metrics, telemetry, tracer,
                          profiler, slo, trace_pid)
+        self.device = device
+        self._telem = jax.device_put(self._telem, device)
         from repro.core.bayes_layer import to_serving
         self.cfg = cfg
         self.adaptive_mode = adaptive_mode
@@ -649,8 +656,8 @@ class SarServingEngine(_EngineBase):
         if head is None:
             head = to_serving(params["head"], self.hcfg)
         self.r_step = policy.r_min if adaptive_mode else policy.r_max
-        self._params = params
-        self._head = head
+        self._params = jax.device_put(params, device)
+        self._head = jax.device_put(head, device)
 
         feat = _sar_featurize_fn(cfg, self.hcfg, chip, slot_axis)
         self._featurize_jit = feat
@@ -679,10 +686,7 @@ class SarServingEngine(_EngineBase):
         if slot_axis is None:
             return None
         if mesh is None:
-            from repro.launch.mesh import abstract_mesh_or
-            mesh = abstract_mesh_or(None)
-        if mesh is None:
-            return None
+            mesh = jax.sharding.get_abstract_mesh()
         size = dict(mesh.shape).get(slot_axis, 0)
         if size <= 0 or n_slots % size:
             return None
@@ -712,7 +716,7 @@ class SarServingEngine(_EngineBase):
                 f"swap_head with {self.n_active} in-flight slots — "
                 f"drain the pool (run()) and swap between segments")
         self.hcfg = hcfg
-        self._head = head
+        self._head = jax.device_put(head, self.device)
         feat = _sar_featurize_fn(self.cfg, hcfg, self._chip,
                                  self._slot_axis)
         self._featurize_jit = feat
@@ -737,7 +741,7 @@ class SarServingEngine(_EngineBase):
             with self.tracer.span("featurize", pid=self.trace_pid,
                                   n_admitted=take), \
                     self.profiler.span("featurize"):
-                rows = self._featurize(jnp.asarray(imgs))
+                rows = self._featurize(jax.device_put(imgs, self.device))
             idx = np.full((self.n_slots,), self.n_slots, np.int32)  # drop
             now = time.perf_counter()
             bases = self._next_bases(take)
@@ -763,9 +767,11 @@ class SarServingEngine(_EngineBase):
             return
         if like is None:
             raise ValueError("ensure_pool needs a template basis pytree")
-        self.pool = jax.tree.map(jnp.zeros_like, like)
-        self.stats = adaptive.init_stats(self.n_slots,
-                                         like["y_mu"].shape[-1])
+        self.pool = jax.tree.map(
+            lambda x: jnp.zeros(x.shape, x.dtype, device=self.device), like)
+        self.stats = jax.device_put(
+            adaptive.init_stats(self.n_slots, like["y_mu"].shape[-1]),
+            self.device)
 
     def active_mask(self) -> np.ndarray:
         """[n_slots] bool — which slots hold an in-flight request."""

@@ -79,25 +79,25 @@ POOL_AXIS = "pool"
 
 def make_pool_mesh(n_pools: int):
     """1-D ``("pool",)`` mesh over the first ``n_pools`` devices."""
-    from repro.launch.mesh import make_mesh_compat
-    return make_mesh_compat((n_pools,), (POOL_AXIS,))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((n_pools,), (POOL_AXIS,))
 
 
 @functools.lru_cache(maxsize=32)
 def _sar_gang_fn(hcfg, policy: TriagePolicy, adaptive_mode: bool,
-                 r_step: int, fused: bool, n_pools: int, mesh,
+                 r_step: int, fused: bool, mesh,
                  tcfg: TelemetryConfig | None = None):
-    """jit (pools, stats, bases, actives[, telems]) -> per-pool results.
+    """jit (pool, stats, base, active[, telem]) -> per-pool results.
 
-    ``pools``/``stats``(/``telems``) are tuples of P per-pool pytrees;
-    ``bases``/``actives`` are [P, S] arrays.  The per-pool trees are
-    stacked INSIDE the jitted graph (the stack is part of the compiled
-    program — no extra host dispatches), shard_mapped over the
-    ``("pool",)`` mesh where each shard runs the engine's un-jitted
-    ``_build_multi_round`` body on its own pool, then sliced back out
-    per pool.  Returns (stats_tuple, verdicts [P,S], fins tree-of-[P,·],
-    rounds [P][, telems_tuple]) — ``rounds`` carries each pool's OWN
-    while_loop trip count.
+    Every argument is one global array sharded over the ``("pool",)``
+    mesh, and device ``p``'s shard IS pool ``p``'s state: ``pool``,
+    ``stats``, ``base`` and ``active`` are the pools' slot-major arrays
+    laid end to end ([P·S, ...]), ``telem`` stacks the pools' telemetry
+    on a leading [P] axis.  Each shard runs the engine's un-jitted
+    ``_build_multi_round`` body on its own pool, so no pool's data
+    leaves its device.  Returns (stats [P·S, ...], verdicts [P·S],
+    fins [P·S, ...], rounds [P][, telem [P, ...]]) with the same
+    layout — ``rounds`` carries each pool's OWN while_loop trip count.
 
     Cached on the same frozen configs as ``_sar_round_fn`` plus the
     (hashable) mesh, so every fleet over the same mesh shares one
@@ -107,48 +107,21 @@ def _sar_gang_fn(hcfg, policy: TriagePolicy, adaptive_mode: bool,
         hcfg=hcfg, policy=policy, adaptive_mode=adaptive_mode,
         r_step=r_step, fused=fused, constrain=lambda t: t, tcfg=tcfg,
         shard=None)
-    from repro.launch.mesh import shard_map_compat
     spec = jax.sharding.PartitionSpec(POOL_AXIS)
-    squeeze = functools.partial(jax.tree.map, lambda x: x[0])
-    expand = functools.partial(jax.tree.map, lambda x: x[None])
-    stack = lambda trees: jax.tree.map(                      # noqa: E731
-        lambda *xs: jnp.stack(xs), *trees)
-
-    def unstack(tree):
-        return tuple(jax.tree.map(lambda x, _p=p: x[_p], tree)
-                     for p in range(n_pools))
 
     if tcfg is None:
         def local(pool, stats, base, active):
-            s, v, f, k = core(squeeze(pool), squeeze(stats),
-                              squeeze(base), squeeze(active))
-            return expand(s), v[None], expand(f), k[None]
-
-        inner = shard_map_compat(local, mesh=mesh,
-                                 in_specs=(spec,) * 4, out_specs=spec)
-
-        def gang(pools, stats, bases, actives):
-            s, v, f, k = inner(stack(pools), stack(stats), bases,
-                               actives)
-            return unstack(s), v, f, k
-
-        return jax.jit(gang)
-
-    def local_t(pool, stats, base, active, telem):
-        s, v, f, k, t = core(squeeze(pool), squeeze(stats),
-                             squeeze(base), squeeze(active),
-                             squeeze(telem))
-        return expand(s), v[None], expand(f), k[None], expand(t)
-
-    inner = shard_map_compat(local_t, mesh=mesh,
-                             in_specs=(spec,) * 5, out_specs=spec)
-
-    def gang_t(pools, stats, bases, actives, telems):
-        s, v, f, k, t = inner(stack(pools), stack(stats), bases,
-                              actives, stack(telems))
-        return unstack(s), v, f, k, unstack(t)
-
-    return jax.jit(gang_t)
+            s, v, f, k = core(pool, stats, base, active)
+            return s, v, f, k[None]
+        n_args = 4
+    else:
+        def local(pool, stats, base, active, telem):
+            s, v, f, k, t = core(pool, stats, base, active,
+                                 jax.tree.map(lambda x: x[0], telem))
+            return s, v, f, k[None], jax.tree.map(lambda x: x[None], t)
+        n_args = 5
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(spec,) * n_args,
+                                 out_specs=spec, check_vma=False))
 
 
 class SarServingFleet:
@@ -170,6 +143,10 @@ class SarServingFleet:
     blind round-robin traffic — and when every pool is saturated the
     remainder stays in the fleet backlog until a retirement frees
     capacity (``backlog_peak`` in the summary tracks the depth).
+
+    With the gang, pool ``p`` lives on mesh device ``p``: its engine's
+    parameters, slot pool, statistics and the pool's telemetry stay on
+    that device across ticks, and the gang dispatch reads them in place.
 
     ``head``/``hcfg``/``chip`` bind every pool to the same (possibly
     degraded) die, as in the single-pool engine."""
@@ -214,6 +191,15 @@ class SarServingFleet:
             for p in range(n_pools):
                 self.tracer.name_process(p + 1, f"pool {p}")
                 self.tracer.name_thread(0, "pool loop", pid=p + 1)
+        if gang is None:
+            gang = n_pools > 1 and len(jax.devices()) >= n_pools
+        if gang and len(jax.devices()) < n_pools:
+            raise ValueError(
+                f"gang dispatch needs >= {n_pools} devices, have "
+                f"{len(jax.devices())}")
+        self.mesh = make_pool_mesh(n_pools) if gang else None
+        devices = (list(self.mesh.devices.flat) if gang
+                   else [None] * n_pools)
         self.engines = [
             SarServingEngine(
                 params, cfg, n_slots=slots_per_pool, policy=policy,
@@ -223,24 +209,27 @@ class SarServingFleet:
                                        tile_program=tile_program),
                 head=head, hcfg=hcfg, chip=chip, fused=fused,
                 telemetry=telemetry, profiler=profiler,
-                tracer=self.tracer, slo=self.slo, trace_pid=p + 1)
+                tracer=self.tracer, slo=self.slo, trace_pid=p + 1,
+                device=devices[p])
             for p in range(n_pools)]
         e0 = self.engines[0]
         self.tcfg = e0.tcfg
-        if gang is None:
-            gang = n_pools > 1 and len(jax.devices()) >= n_pools
-        self.mesh = None
         self._gang = None
+        self._telem = None
         if gang:
-            if len(jax.devices()) < n_pools:
-                raise ValueError(
-                    f"gang dispatch needs >= {n_pools} devices, have "
-                    f"{len(jax.devices())} (XLA_FLAGS="
-                    f"--xla_force_host_platform_device_count=N)")
-            self.mesh = make_pool_mesh(n_pools)
+            self._sharding = jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec(POOL_AXIS))
             self._gang = _sar_gang_fn(
                 e0.hcfg, policy, adaptive_mode, e0.r_step, fused,
-                n_pools, self.mesh, self.tcfg)
+                self.mesh, self.tcfg)
+            if self.tcfg is not None:
+                # the pools' telemetry, stacked on a leading pool axis
+                # (row p on device p); handed to the engines at drain
+                self._telem = jax.device_put(
+                    jax.tree.map(lambda *xs: np.stack(xs),
+                                 *jax.device_get([e._telem
+                                                  for e in self.engines])),
+                    self._sharding)
         self.backlog: deque[Request] = deque()
         self.routes: dict[int, int] = {}          # rid -> pool id
         self.host_syncs = 0
@@ -305,37 +294,54 @@ class SarServingFleet:
         return sum(e.n_active for e in self.engines)
 
     # -- dispatch -------------------------------------------------------
+    def _global(self, trees):
+        """The pools' per-device arrays laid end to end as one global
+        array per leaf, sharded over the pool mesh — no copy, no
+        dispatch: device p's shard is pool p's own buffer."""
+        return jax.tree.map(
+            lambda *xs: jax.make_array_from_single_device_arrays(
+                (sum(x.shape[0] for x in xs),) + xs[0].shape[1:],
+                self._sharding, list(xs)),
+            *trees)
+
+    @staticmethod
+    def _local(tree, device):
+        """``device``'s shard of every global leaf in ``tree`` (no copy)."""
+        return jax.tree.map(
+            lambda x: next(s.data for s in x.addressable_shards
+                           if s.device == device), tree)
+
     def _dispatch_gang(self, actives: list[np.ndarray]) -> list[int]:
         """One shard_map'd round for ALL pools; one host sync."""
         template = next((e.pool for e in self.engines
                          if e.pool is not None), None)
         for eng in self.engines:
             eng.ensure_pool(like=template)
-        pools = tuple(e.pool for e in self.engines)
-        stats = tuple(e.stats for e in self.engines)
-        bases = jnp.asarray(np.stack([e.base for e in self.engines]))
-        acts = jnp.asarray(np.stack(actives))
+        pool = self._global([e.pool for e in self.engines])
+        stats = self._global([e.stats for e in self.engines])
+        base = jax.device_put(np.concatenate([e.base for e in self.engines]),
+                              self._sharding)
+        active = jax.device_put(np.concatenate(actives), self._sharding)
         with self.profiler.span("dispatch"):
             if self.tcfg is None:
                 stats_out, verdicts, fins, rounds = self._gang(
-                    pools, stats, bases, acts)
+                    pool, stats, base, active)
             else:
-                telems = tuple(e._telem for e in self.engines)
-                stats_out, verdicts, fins, rounds, telems_out = \
-                    self._gang(pools, stats, bases, acts, telems)
-                for eng, t in zip(self.engines, telems_out):
-                    eng._telem = t
+                stats_out, verdicts, fins, rounds, self._telem = \
+                    self._gang(pool, stats, base, active, self._telem)
         # ONE blocking pull for the whole fleet: every pool's verdicts,
         # finalized stats and trip counts arrive in a single sync.
+        shape = (self.n_pools, self.slots_per_pool)
         with self.profiler.span("triage_loop"):
-            verdicts = np.asarray(verdicts)
+            verdicts = np.asarray(verdicts).reshape(shape)
             rounds = np.asarray(rounds)
-            fins = {k: np.asarray(v) for k, v in fins.items()}
+            fins = {k: np.asarray(v).reshape(shape + v.shape[1:])
+                    for k, v in fins.items()}
         self.host_syncs += 1
         t_verdict = time.perf_counter()
         with self.profiler.span("retirement"):
             for p, eng in enumerate(self.engines):
-                eng.stats = stats_out[p]
+                eng.stats = self._local(stats_out, eng.device)
                 if actives[p].any():
                     fin_p = {k: v[p] for k, v in fins.items()}
                     spent = eng.r_step * int(rounds[p])
@@ -426,6 +432,10 @@ class SarServingFleet:
     def drain(self) -> dict:
         """Attach per-pool telemetry/perf and build the fleet summary
         (the shared SLO snapshot lands on the fleet summary only)."""
+        if self._telem is not None:
+            for p, eng in enumerate(self.engines):
+                eng._telem = jax.tree.map(
+                    lambda x: x[0], self._local(self._telem, eng.device))
         for eng in self.engines:
             if eng.tcfg is not None:
                 eng.metrics.attach_telemetry(eng.telemetry_snapshot())

@@ -88,6 +88,44 @@ def test_trace_capture_none_is_noop():
         pass
 
 
+def test_trace_capture_raises_when_profiler_cannot_start(monkeypatch,
+                                                        tmp_path):
+    def refuse(log_dir):
+        raise RuntimeError("profiler busy")
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler busy"):
+        with prof.trace_capture(str(tmp_path)):
+            pass
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_enable_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """``$JAX_COMPILATION_CACHE_DIR`` is left to JAX; without it the
+    cache is one fixed directory in the checkout, whatever the cwd.
+    Either way every compile is cached, however short."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import enable_compile_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    monkeypatch.chdir(tmp_path)
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        jax.config.update("jax_compilation_cache_dir", env_dir)
+        want = env_dir
+    try:
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+
+
 # ----------------------------------------------------------------------
 # compile-cache invariant (satellite: compilation caching regression)
 # ----------------------------------------------------------------------

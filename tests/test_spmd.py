@@ -30,14 +30,14 @@ def test_sharded_moe_matches_gspmd_oracle():
     run_spmd("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.models.moe import init_moe, moe_apply, make_sharded_moe
-from repro.launch.mesh import make_mesh_compat, mesh_context
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 E, D, F, k = 4, 32, 64, 2
 p = init_moe(jax.random.PRNGKey(0), 1, D, F, E)
 r, wi, wg, wo = p["router"][0], p["wi"][0], p["wg"][0], p["wo"][0]
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, D))
 y_ref, _ = moe_apply(x, r, wi, wg, wo, top_k=k, capacity_factor=8.0)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     moe = make_sharded_moe(mesh, top_k=k, capacity_factor=8.0,
                            n_experts=E, dp_axes=("data",))
     y, _ = jax.jit(moe)(x, r, wi, wg, wo)
@@ -60,8 +60,8 @@ from repro.data.tokens import TokenPipelineConfig, batch_at
 
 cfg0 = get_config("qwen3-0.6b", smoke=True)
 opt_cfg = AdamWConfig()
-from repro.launch.mesh import make_mesh_compat, mesh_context
-mesh = make_mesh_compat((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 pipe = TokenPipelineConfig(vocab=cfg0.vocab, seq_len=16, global_batch=4)
 batch = batch_at(pipe, 0)
 
@@ -71,7 +71,7 @@ opt = init_opt_state(params)
 ref_step = make_train_step(cfg0, opt_cfg)
 p_ref, o_ref, m_ref = jax.jit(ref_step)(params, opt, batch)
 
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     jitted, _, _, cfg2 = jit_train_step(cfg0, mesh, opt_cfg, 16, 4)
     p_sh, o_sh, m_sh = jitted(params, opt, batch)
 assert abs(float(m_ref["loss"]) - float(m_sh["loss"])) < 2e-2, (
@@ -110,7 +110,7 @@ def test_sharded_serving_pool_matches_single_device():
     and verdict as the unsharded engine."""
     run_spmd("""
 import jax, numpy as np
-from repro.launch.mesh import make_mesh_compat, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.launch.serve import make_sar_stream
 from repro.models.sar_cnn import SarCnnConfig, init_sar_cnn
 from repro.serving import SarServingEngine, TriagePolicy
@@ -130,8 +130,8 @@ def run(slot_axis, mesh):
             for r in eng.metrics.records}
 
 ref = run(None, None)
-mesh = make_mesh_compat((2, 1), ("data", "model"))
-with mesh_context(mesh):
+mesh = make_mesh((2, 1), ("data", "model"))
+with jax.set_mesh(mesh):
     got = run("data", mesh)
 assert set(ref) == set(got) == set(range(10))
 for rid in ref:
@@ -154,7 +154,7 @@ def test_shard_map_fused_kernel_bit_identical():
     run_spmd("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.hlo_analysis import largest_intermediate_bytes
-from repro.launch.mesh import make_mesh_compat, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.launch.serve import make_sar_stream
 from repro.models.sar_cnn import SarCnnConfig, init_sar_cnn
 from repro.serving import SarServingEngine, TriagePolicy
@@ -200,11 +200,11 @@ def round_peak(eng):
                            jnp.ones((b,), bool)).compile().as_text()
     return largest_intermediate_bytes(txt)
 
-mesh = make_mesh_compat((2, 1), ("data", "model"))
+mesh = make_mesh((2, 1), ("data", "model"))
 for tag, extra in (("ideal", {}), ("chip2.5", chip_head())):
     ref, eng_ref = run(None, None, True, extra)
     jnp_ref, _ = run(None, None, False, extra)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         got, eng_sh = run("data", mesh, True, extra)
     assert eng_sh._mesh is not None, tag   # shard_map-native path taken
     assert set(ref) == set(got) == set(range(192)), tag
@@ -214,7 +214,7 @@ for tag, extra in (("ideal", {}), ("chip2.5", chip_head())):
     assert eng_ref.host_syncs == eng_sh.host_syncs, (
         tag, eng_ref.host_syncs, eng_sh.host_syncs)
     peak_ref = round_peak(eng_ref)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         peak_sh = round_peak(eng_sh)
     assert peak_sh <= peak_ref * 1.01, (tag, peak_sh, peak_ref)
     print(tag, "OK", eng_ref.host_syncs, peak_ref, peak_sh)
@@ -250,6 +250,34 @@ print("OK")
 """, devices=8)
 
 
+def test_fleet_gang_keeps_each_pool_on_its_device():
+    """With the gang, pool p's parameters, slot pool, statistics and
+    telemetry live on mesh device p only, across ticks and after the
+    drain — nothing is replicated or parked on device 0."""
+    run_spmd("""
+import jax
+from repro.launch.serve import make_sar_stream
+from repro.models.sar_cnn import SarCnnConfig, init_sar_cnn
+from repro.serving import SarServingFleet
+
+cfg = SarCnnConfig()
+params = init_sar_cnn(jax.random.PRNGKey(3), cfg)
+fleet = SarServingFleet(params, cfg, n_pools=4, slots_per_pool=8,
+                        gang=True)
+for r in make_sar_stream(64, corrupt_frac=0.25):
+    fleet.submit(r)
+out = fleet.run()
+assert out["gang"] and out["decisions"] == 64
+devices = list(fleet.mesh.devices.flat)
+for p, eng in enumerate(fleet.engines):
+    leaves = jax.tree.leaves((eng._params, eng._head, eng.pool, eng.stats,
+                              eng._telem))
+    placed = set().union(*(x.devices() for x in leaves))
+    assert placed == {devices[p]}, (p, placed)
+print("OK")
+""", devices=4)
+
+
 def test_microbatched_step_matches_full_batch():
     run_spmd("""
 import jax, jax.numpy as jnp, numpy as np
@@ -260,8 +288,8 @@ from repro.models.registry import get_api
 from repro.data.tokens import TokenPipelineConfig, batch_at
 
 cfg0 = get_config("qwen3-1.7b", smoke=True)
-from repro.launch.mesh import make_mesh_compat, mesh_context
-mesh = make_mesh_compat((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 pipe = TokenPipelineConfig(vocab=cfg0.vocab, seq_len=16, global_batch=8)
 batch = batch_at(pipe, 0)
 api = get_api(cfg0)
@@ -273,11 +301,11 @@ def fresh():
     return p, init_opt_state(p)
 
 params, opt = fresh()
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     j1, _, _, _ = jit_train_step(cfg0, mesh, AdamWConfig(), 16, 8)
     p1, o1, m1 = j1(params, opt, batch)
 params, opt = fresh()
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     j4, _, _, _ = jit_train_step(cfg0, mesh, AdamWConfig(), 16, 8,
                                  microbatches=4)
     p4, o4, m4 = j4(params, opt, batch)

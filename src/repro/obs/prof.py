@@ -16,7 +16,11 @@ changes a compiled graph (asserted in tests/test_obs.py):
     buckets.  Stages are open-ended strings so the mission driver can
     profile its own phases (detector / rollout / drain) through the
     same exporter.  Exported as Prometheus histograms via
-    ``obs.registry.add_stage_profile``.
+    ``obs.registry.add_stage_profile``.  ``span`` is the program's one
+    span primitive: each span is also a ``jax.profiler`` trace
+    annotation of the same name, so a ``--profile`` capture shows the
+    stages on the device trace's clock.  ``track_gc`` adds every
+    garbage collection as a ``gc`` span.
 
 Compile-event counters
     ``count_build(name)`` ticks once per *executable construction* in
@@ -45,12 +49,17 @@ Compile-event counters
 
 from __future__ import annotations
 
+import bisect
 import contextlib
+import gc
+import math
 import time
+import weakref
 from collections import Counter
 from typing import Any, Callable
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # ----------------------------------------------------------------------
 # stage profiler
@@ -65,6 +74,63 @@ SERVING_STAGES = ("admission", "featurize", "dispatch", "triage_loop",
 # enough for interpret-mode CPU dispatches and tight enough that a TPU
 # round's sub-ms latencies don't all land in one bin.
 _EDGES = np.logspace(-6, 1, 29)
+# The same edges as Python floats: ``observe`` bins one float at a time,
+# where ``bisect`` costs a fraction of a numpy call.
+_EDGE_LIST = _EDGES.tolist()
+
+
+class _Span:
+    """One timed stage: a trace annotation around a perf_counter pair."""
+
+    __slots__ = ("_observe", "_stage", "_trace", "_t0")
+
+    def __init__(self, observe, stage: str, trace):
+        self._observe = observe
+        self._stage = stage
+        self._trace = trace
+
+    def __enter__(self):
+        self._trace.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._trace.__exit__(*exc)
+        self._observe(self._stage, dt)
+        return False
+
+
+class _GcSpans:
+    """``gc.callbacks`` hook: every collection is a ``gc`` trace span and a
+    ``gc`` observation in ``profiler`` (a full one, generation 2, also a
+    ``gc_full`` one).  ``holders`` counts the owners keeping it hooked."""
+
+    def __init__(self, profiler: "StageProfiler"):
+        self.profiler = profiler
+        self.holders = 0
+        self._trace = None
+        self._t0 = 0.0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._trace = TraceAnnotation("gc")
+            self._trace.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        if self._trace is None:          # hooked in mid-collection
+            return
+        dt = time.perf_counter() - self._t0
+        self.close()
+        self.profiler.observe("gc", dt)
+        if info.get("generation") == 2:
+            self.profiler.observe("gc_full", dt)
+
+    def close(self) -> None:
+        """End the open trace span, if a collection is under way."""
+        if self._trace is not None:
+            self._trace.__exit__(None, None, None)
+            self._trace = None
 
 
 class StageProfiler:
@@ -79,10 +145,11 @@ class StageProfiler:
     edges = _EDGES
 
     def __init__(self):
-        self._counts: dict[str, np.ndarray] = {}
-        self._over: Counter = Counter()
-        self._total_s: Counter = Counter()
-        self._n: Counter = Counter()
+        self._counts: dict[str, list[int]] = {}
+        self._over: dict[str, int] = {}
+        self._total_s: dict[str, float] = {}
+        self._n: dict[str, int] = {}
+        self._gc_hook: _GcSpans | None = None
 
     @property
     def enabled(self) -> bool:
@@ -98,24 +165,50 @@ class StageProfiler:
             return
         dt_s = max(float(dt_s), 0.0)
         if stage not in self._counts:
-            self._counts[stage] = np.zeros(len(_EDGES) - 1, np.int64)
+            self._counts[stage] = [0] * (len(_EDGE_LIST) - 1)
+            self._over[stage] = self._n[stage] = 0
+            self._total_s[stage] = 0.0
         self._n[stage] += 1
-        if np.isfinite(dt_s):
+        if dt_s != math.inf:
             self._total_s[stage] += dt_s
-        if dt_s >= _EDGES[-1] or not np.isfinite(dt_s):
+        if dt_s >= _EDGE_LIST[-1]:                     # +inf included
             self._over[stage] += 1
             return
         self._counts[stage][
-            np.searchsorted(_EDGES, dt_s, side="right") - 1 if
-            dt_s >= _EDGES[0] else 0] += 1
+            bisect.bisect_right(_EDGE_LIST, dt_s) - 1 if
+            dt_s >= _EDGE_LIST[0] else 0] += 1
 
-    @contextlib.contextmanager
-    def span(self, stage: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.observe(stage, time.perf_counter() - t0)
+    def span(self, stage: str, *, name: str | None = None,
+             step: int | None = None) -> _Span:
+        """Time the enclosed work as one ``stage`` observation, inside a
+        ``jax.profiler`` trace annotation named ``name`` (default: the
+        stage).  With ``step`` the annotation is a
+        ``StepTraceAnnotation`` carrying that step number."""
+        label = name or stage
+        trace = (TraceAnnotation(label) if step is None
+                 else StepTraceAnnotation(label, step_num=step))
+        return _Span(self.observe, stage, trace)
+
+    def track_gc(self, owner) -> None:
+        """Observe every garbage collection of the process (stage
+        ``gc``; full ones also ``gc_full``) as a ``gc`` span, for as long
+        as ``owner`` lives.  One hook per profiler, however many owners
+        share it, so a shared profiler counts each pause once; the hook
+        leaves ``gc.callbacks`` when its last owner is dropped."""
+        if self._gc_hook is None:
+            self._gc_hook = _GcSpans(self)
+            gc.callbacks.append(self._gc_hook)
+        self._gc_hook.holders += 1
+        weakref.finalize(owner, self._release_gc)
+
+    def _release_gc(self) -> None:
+        hook = self._gc_hook
+        hook.holders -= 1
+        if hook.holders == 0:
+            # a finalizer may run inside a collection: close its span
+            hook.close()
+            gc.callbacks.remove(hook)
+            self._gc_hook = None
 
     def snapshot(self) -> dict[str, Any]:
         """{stage: {count, total_s, mean_s, p50/p95/p99_s, counts,
@@ -126,15 +219,15 @@ class StageProfiler:
         order = [s for s in SERVING_STAGES if s in self._counts]
         order += [s for s in self._counts if s not in SERVING_STAGES]
         for stage in order:
-            n = int(self._n[stage])
+            n = self._n[stage]
             rec = {
                 "count": n,
                 "total_s": float(self._total_s[stage]),
                 "mean_s": float(self._total_s[stage]) / n if n else
                 float("nan"),
-                "counts": self._counts[stage].tolist(),
-                "overflow": int(self._over[stage]),
-                "edges": _EDGES.tolist(),
+                "counts": list(self._counts[stage]),
+                "overflow": self._over[stage],
+                "edges": list(_EDGE_LIST),
             }
             rec["p50_s"] = quantile(rec, 0.50)
             rec["p95_s"] = quantile(rec, 0.95)
@@ -144,7 +237,8 @@ class StageProfiler:
 
 
 class _NullStageProfiler(StageProfiler):
-    """No-op profiler so engine call sites never branch."""
+    """No-op profiler so engine call sites never branch: it times
+    nothing, traces nothing and hooks no collector."""
 
     @property
     def enabled(self) -> bool:
@@ -153,14 +247,17 @@ class _NullStageProfiler(StageProfiler):
     def observe(self, stage, dt_s):
         pass
 
-    @contextlib.contextmanager
-    def span(self, stage):
-        yield
+    def span(self, stage, *, name=None, step=None):
+        return _NULL_SPAN
+
+    def track_gc(self, owner):
+        pass
 
     def snapshot(self):
         return {}
 
 
+_NULL_SPAN = contextlib.nullcontext()
 NULL_PROFILER = _NullStageProfiler()
 
 

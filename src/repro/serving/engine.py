@@ -419,6 +419,32 @@ def _lm_token_fn(hcfg: BayesHeadConfig, policy: TriagePolicy,
     return jax.jit(token_decision)
 
 
+def pull_round(profiler: StageProfiler, verdict, fin: dict, rounds):
+    """The host's one blocking pull of a round dispatch's results, as
+    the ``triage_loop`` span: ``round_wait`` until the round's outputs
+    are ready, then one ``verdict_pull`` span per array copied to the
+    host (the verdicts, each ``fin`` key, the trip count), so the span
+    count is the transfer count.  Returns the host copies
+    ``(verdict, fin, rounds)``.  Shared by the engine and the fleet.
+
+    The verdicts' copy is queued before the wait, as a bare
+    ``np.asarray`` would queue it, so it still starts the moment the
+    round ends: the split adds no host round trip to the pull."""
+    with profiler.span("triage_loop"):
+        with profiler.span("round_wait"):
+            verdict.copy_to_host_async()
+            jax.block_until_ready((verdict, fin, rounds))
+        with profiler.span("verdict_pull"):
+            verdict = np.asarray(verdict)
+        host_fin = {}
+        for k, v in fin.items():
+            with profiler.span("verdict_pull"):
+                host_fin[k] = np.asarray(v)
+        with profiler.span("verdict_pull"):
+            rounds = np.asarray(rounds)
+    return verdict, host_fin, rounds
+
+
 class _EngineBase:
     """Queue + slot bookkeeping shared by both engines."""
 
@@ -454,6 +480,9 @@ class _EngineBase:
         if profiler is True:
             profiler = StageProfiler()
         self.profiler: StageProfiler = profiler or NULL_PROFILER
+        # Collector pauses land in the same profiler (stage ``gc``) and
+        # trace; engines sharing a profiler share its one hook.
+        self.profiler.track_gc(self)
         # Host-side SLO lifecycle tracking (obs/slo): retired records
         # stream into time-to-verdict histograms.  True for a fresh
         # tracker this engine owns (and attaches to its summary), an
@@ -599,8 +628,9 @@ class SarServingEngine(_EngineBase):
         degraded instance).  Default: golden-chip head from ``params``.
 
         ``profiler``: host-side per-stage latency histograms
-        (obs/prof.StageProfiler) over admission / featurize / dispatch /
-        triage_loop / retirement — True for a fresh profiler, an
+        (obs/prof.StageProfiler) over the tick (``step``) and its
+        stages, and the collector's pauses (``gc``), each also a
+        ``jax.profiler`` trace span — True for a fresh profiler, an
         existing StageProfiler to share one across engines, False to
         disable.  Pure host clock arithmetic: no syncs, no graph change.
 
@@ -671,6 +701,7 @@ class SarServingEngine(_EngineBase):
                                     self.tcfg, mesh=self._mesh)
         self._chip = chip
         self._slot_axis = slot_axis
+        self.ticks = 0                 # step() calls: the trace's step number
         self.pool = None
         self.stats = None
         self.base = None
@@ -733,29 +764,32 @@ class SarServingEngine(_EngineBase):
         if take == 0:
             return
         with self.profiler.span("admission"):
-            reqs = [self.queue.popleft() for _ in range(take)]
-            imgs = np.stack([np.asarray(r.payload) for r in reqs])
-            if take < self.n_slots:                   # fixed-shape batch
-                pad = np.repeat(imgs[-1:], self.n_slots - take, axis=0)
-                imgs = np.concatenate([imgs, pad], axis=0)
+            with self.profiler.span("admit_stack"):
+                reqs = [self.queue.popleft() for _ in range(take)]
+                imgs = np.stack([np.asarray(r.payload) for r in reqs])
+                if take < self.n_slots:               # fixed-shape batch
+                    pad = np.repeat(imgs[-1:], self.n_slots - take, axis=0)
+                    imgs = np.concatenate([imgs, pad], axis=0)
             with self.tracer.span("featurize", pid=self.trace_pid,
                                   n_admitted=take), \
                     self.profiler.span("featurize"):
                 rows = self._featurize(jax.device_put(imgs, self.device))
-            idx = np.full((self.n_slots,), self.n_slots, np.int32)  # drop
-            now = time.perf_counter()
-            bases = self._next_bases(take)
-            for j, req in enumerate(reqs):
-                s = self.free.pop()
-                idx[j] = s
-                self.slots[s].req = req
-                self.slots[s].admit_s = now
-                self.base[s] = bases[j]
-            idxj = jnp.asarray(idx)
-            self.ensure_pool(like=rows)
-            self.pool = self._scatter(self.pool, rows, idxj)
-            self.stats = self._stats_reset(self.stats, idxj)
-            self.metrics.mark(now)
+            # slot assignment, then the enqueued scatter and stats reset
+            with self.profiler.span("admit_enqueue"):
+                idx = np.full((self.n_slots,), self.n_slots, np.int32)
+                now = time.perf_counter()
+                bases = self._next_bases(take)
+                for j, req in enumerate(reqs):
+                    s = self.free.pop()
+                    idx[j] = s
+                    self.slots[s].req = req
+                    self.slots[s].admit_s = now
+                    self.base[s] = bases[j]
+                idxj = jnp.asarray(idx)
+                self.ensure_pool(like=rows)
+                self.pool = self._scatter(self.pool, rows, idxj)
+                self.stats = self._stats_reset(self.stats, idxj)
+                self.metrics.mark(now)
 
     def ensure_pool(self, like: dict | None = None) -> None:
         """Materialize the (pool, stats) device state without waiting
@@ -804,43 +838,47 @@ class SarServingEngine(_EngineBase):
     def step(self) -> bool:
         """One scheduler tick: admit from the queue, dispatch the
         device-resident escalation round, retire decided slots.
-        Returns False when nothing was active (idle tick)."""
-        self._admit()
-        if self.n_active == 0:
-            return False
-        active = self.active_mask()
-        self._stamp_first_dispatch(active)
-        t_disp = self.tracer.now()
-        with self.profiler.span("dispatch"):
-            if self.tcfg is None:
-                self.stats, verdict, fin, rounds = self._round(
-                    self.pool, self.stats, jnp.asarray(self.base),
-                    jnp.asarray(active))
-            else:
-                (self.stats, verdict, fin, rounds,
-                 self._telem) = self._round(
-                    self.pool, self.stats, jnp.asarray(self.base),
-                    jnp.asarray(active), self._telem)
-        # ONE blocking host↔device round trip per dispatch — the
-        # while_loop above already ran every all-escalate round.
-        # The triage_loop span measures exactly that pull: the host
-        # waiting on the device-resident escalation.
-        with self.profiler.span("triage_loop"):
-            verdict = np.asarray(verdict)
-            fin = {k: np.asarray(v) for k, v in fin.items()}
+        Returns False when nothing was active (idle tick).
+
+        The tick is one ``tick`` span (a ``sar_tick`` step in the
+        trace) over admission, slot_mask, dispatch, triage_loop and
+        retirement."""
+        with self.profiler.span("tick", name="sar_tick", step=self.ticks):
+            self.ticks += 1
+            self._admit()
+            if self.n_active == 0:
+                return False
+            with self.profiler.span("slot_mask"):
+                active = self.active_mask()
+                self._stamp_first_dispatch(active)
+            t_disp = self.tracer.now()
+            with self.profiler.span("dispatch"):
+                if self.tcfg is None:
+                    self.stats, verdict, fin, rounds = self._round(
+                        self.pool, self.stats, jnp.asarray(self.base),
+                        jnp.asarray(active))
+                else:
+                    (self.stats, verdict, fin, rounds,
+                     self._telem) = self._round(
+                        self.pool, self.stats, jnp.asarray(self.base),
+                        jnp.asarray(active), self._telem)
+            # ONE blocking host↔device round trip per dispatch — the
+            # while_loop above already ran every all-escalate round.
+            verdict, fin, rounds = pull_round(self.profiler, verdict, fin,
+                                              rounds)
             spent = self.r_step * int(rounds)
-        self.host_syncs += 1
-        t_verdict = time.perf_counter()
-        if self.tracer.enabled:
-            self.tracer.complete(
-                "sar_rounds", t_disp, self.tracer.now() - t_disp,
-                pid=self.trace_pid,
-                rounds=int(rounds), n_active=int(active.sum()),
-                samples_per_slot=spent)
-        with self.profiler.span("retirement"):
-            self._retire_decided(active, verdict, fin, spent,
-                                 verdict_s=t_verdict)
-        return True
+            self.host_syncs += 1
+            t_verdict = time.perf_counter()
+            if self.tracer.enabled:
+                self.tracer.complete(
+                    "sar_rounds", t_disp, self.tracer.now() - t_disp,
+                    pid=self.trace_pid,
+                    rounds=int(rounds), n_active=int(active.sum()),
+                    samples_per_slot=spent)
+            with self.profiler.span("retirement"):
+                self._retire_decided(active, verdict, fin, spent,
+                                     verdict_s=t_verdict)
+            return True
 
     def drain(self) -> dict:
         """Attach telemetry/perf/SLO snapshots and build the summary."""

@@ -70,7 +70,7 @@ from repro.obs.slo import NULL_SLO, SloTracker
 from repro.obs.telemetry import TelemetryConfig, merge_snapshots
 from repro.obs.trace import NULL_TRACER
 from repro.serving.engine import (Request, SarServingEngine,
-                                  _build_multi_round)
+                                  _build_multi_round, pull_round)
 from repro.serving.metrics import ServingMetrics
 from repro.serving.triage import TriagePolicy
 
@@ -332,11 +332,10 @@ class SarServingFleet:
         # ONE blocking pull for the whole fleet: every pool's verdicts,
         # finalized stats and trip counts arrive in a single sync.
         shape = (self.n_pools, self.slots_per_pool)
-        with self.profiler.span("triage_loop"):
-            verdicts = np.asarray(verdicts).reshape(shape)
-            rounds = np.asarray(rounds)
-            fins = {k: np.asarray(v).reshape(shape + v.shape[1:])
-                    for k, v in fins.items()}
+        verdicts, fins, rounds = pull_round(self.profiler, verdicts, fins,
+                                            rounds)
+        verdicts = verdicts.reshape(shape)
+        fins = {k: v.reshape(shape + v.shape[1:]) for k, v in fins.items()}
         self.host_syncs += 1
         t_verdict = time.perf_counter()
         with self.profiler.span("retirement"):
@@ -365,10 +364,9 @@ class SarServingFleet:
                      eng._telem) = eng._round(
                         eng.pool, eng.stats, jnp.asarray(eng.base),
                         jnp.asarray(active), eng._telem)
-            with self.profiler.span("triage_loop"):
-                verdict = np.asarray(verdict)
-                fin = {k: np.asarray(v) for k, v in fin.items()}
-                spent = eng.r_step * int(rounds)
+            verdict, fin, rounds = pull_round(self.profiler, verdict, fin,
+                                              rounds)
+            spent = eng.r_step * int(rounds)
             self.host_syncs += 1
             eng.host_syncs += 1
             trips[p] = int(rounds)

@@ -121,6 +121,20 @@ class _Hist:
         i = int(np.searchsorted(_EDGES, dt, side="right")) - 1
         self.counts[max(i, 0)] += 1
 
+    def observe_many(self, dt_s: np.ndarray) -> None:
+        """Fold a batch with ``observe``'s bin semantics: one
+        ``searchsorted`` and one ``bincount`` for the whole batch."""
+        dt = np.maximum(dt_s[~np.isnan(dt_s)], 0.0)
+        if not dt.size:
+            return
+        self.total_s += float(dt.sum())
+        self.n += int(dt.size)
+        over = dt >= _EDGES[-1]
+        self.overflow += int(over.sum())
+        i = np.searchsorted(_EDGES, dt[~over], side="right") - 1
+        self.counts += np.bincount(np.maximum(i, 0),
+                                   minlength=len(self.counts))
+
     def to_dict(self) -> dict[str, Any]:
         return {"count": int(self.n), "total_s": self.total_s,
                 "counts": self.counts.tolist(),
@@ -167,31 +181,52 @@ class SloTracker:
 
     def observe(self, rec) -> None:
         """Fold one retired RequestRecord into the stream."""
-        t = rec.verdict_latency_s
-        if math.isnan(t):
-            t = rec.latency_s
-        self._n += 1
-        self._ttv.observe(t)
-        self._queue.observe(rec.queue_latency_s)
-        self._service.observe(rec.service_latency_s)
-        name = _VERDICTS.get(int(rec.verdict), str(int(rec.verdict)))
-        h = self._by_verdict.get(name)
-        if h is None:
-            h = self._by_verdict[name] = _Hist()
-        h.observe(t)
-        r = int(round(rec.n_samples / max(rec.n_decisions, 1)))
-        hr = self._by_r.get(r)
-        if hr is None:
-            hr = self._by_r[r] = _Hist()
-        hr.observe(t)
+        self.observe_many((rec,))
+
+    def observe_many(self, recs) -> None:
+        """Fold a batch of retired RequestRecords (a tick's retirements)
+        into the stream: each field gathered once, each histogram fed
+        the batch in one call.  Bins, counts and violations are those
+        of folding the records one at a time."""
+        if not recs:
+            return
+        arrival_pc = np.array([r.arrival_pc for r in recs], np.float64)
+        arrival_s = np.array([r.arrival_s for r in recs], np.float64)
+        admit = np.array([r.admit_s for r in recs], np.float64)
+        done = np.array([r.done_s for r in recs], np.float64)
+        verdict_s = np.array([r.verdict_s for r in recs], np.float64)
+        verdict = np.array([r.verdict for r in recs], np.int64)
+        r_at = np.rint(
+            np.array([r.n_samples for r in recs], np.float64)
+            / np.maximum(np.array([r.n_decisions for r in recs],
+                                  np.float64), 1.0)).astype(np.int64)
+        # RequestRecord's intervals: arrival_pc unless it is not finite
+        arrival = np.where(np.isfinite(arrival_pc), arrival_pc, arrival_s)
+        t = verdict_s - arrival
+        t = np.where(np.isnan(t), done - arrival, t)
+        self._n += len(recs)
+        self._ttv.observe_many(t)
+        self._queue.observe_many(admit - arrival)
+        self._service.observe_many(done - admit)
+        for code in np.unique(verdict).tolist():
+            name = _VERDICTS.get(code, str(code))
+            h = self._by_verdict.get(name)
+            if h is None:
+                h = self._by_verdict[name] = _Hist()
+            h.observe_many(t[verdict == code])
+        for r in np.unique(r_at).tolist():
+            hr = self._by_r.get(r)
+            if hr is None:
+                hr = self._by_r[r] = _Hist()
+            hr.observe_many(t[r_at == r])
         for k, slo in enumerate(self.slos):
-            if t > slo.target_s:
-                self._violations[k] += 1
-        arr = rec.arrival_pc
-        if math.isnan(arr):
-            arr = rec.arrival_s
-        self._first_arrival = min(self._first_arrival, arr)
-        self._last_done = max(self._last_done, rec.done_s)
+            self._violations[k] += int((t > slo.target_s).sum())
+        # the span's ends skip NaN stamps (fmin/fmax), as min/max did
+        first = np.where(np.isnan(arrival_pc), arrival_s, arrival_pc)
+        self._first_arrival = float(
+            np.fmin.reduce(first, initial=self._first_arrival))
+        self._last_done = float(
+            np.fmax.reduce(done, initial=self._last_done))
 
     # ---- fleet path ----
 
@@ -278,7 +313,7 @@ class _NullSloTracker(SloTracker):
     def enabled(self) -> bool:
         return False
 
-    def observe(self, rec) -> None:
+    def observe_many(self, recs) -> None:
         pass
 
     def observe_router(self, dt_s) -> None:

@@ -528,7 +528,10 @@ class _EngineBase:
 
     def _retire(self, slot_idx: int, verdict: int, fin: dict,
                 extra_samples: int,
-                verdict_s: float = float("nan")) -> None:
+                verdict_s: float = float("nan")) -> RequestRecord:
+        """Record one decided request and free its slot.  Returns the
+        record; the caller folds a tick's records into the SLO tracker
+        in one ``_fold_slo`` call."""
         slot = self.slots[slot_idx]
         req = slot.req
         now = time.perf_counter()
@@ -546,7 +549,6 @@ class _EngineBase:
             verdict_s=verdict_s,
         )
         self.metrics.record(rec)
-        self.slo.observe(rec)
         if self.tracer.enabled:
             start = slot.admit_s - self.tracer.t0
             self.tracer.complete(
@@ -563,6 +565,14 @@ class _EngineBase:
         slot.n_samples = slot.n_decisions = 0
         slot.first_dispatch_s = 0.0
         self.free.append(slot_idx)
+        return rec
+
+    def _fold_slo(self, recs: list[RequestRecord]) -> None:
+        """One tick's retirements into the SLO tracker as one batch
+        (span ``slo_fold``, opened only when the tick retired any)."""
+        if recs:
+            with self.profiler.span("slo_fold"):
+                self.slo.observe_many(recs)
 
     def telemetry_snapshot(self) -> dict | None:
         """Host snapshot of the device-resident telemetry (one sync)."""
@@ -817,16 +827,17 @@ class SarServingEngine(_EngineBase):
         to every active slot, retire those whose verdict left ESCALATE.
         ``verdict_s`` is the perf_counter stamp of the host sync that
         pulled these verdicts.  Returns the number retired."""
-        retired = 0
+        recs = []
         for i in np.nonzero(active)[0]:
             self.slots[i].n_samples += spent
             if verdict[i] != ESCALATE:
                 self.slots[i].n_decisions = 1
                 # n_samples already accumulated; fin["n"] agrees
-                self._retire(i, verdict[i], fin, extra_samples=0,
-                             verdict_s=verdict_s)
-                retired += 1
-        return retired
+                recs.append(self._retire(i, verdict[i], fin,
+                                         extra_samples=0,
+                                         verdict_s=verdict_s))
+        self._fold_slo(recs)
+        return len(recs)
 
     # -- main loop ------------------------------------------------------
     def start(self) -> None:
@@ -1152,6 +1163,7 @@ class LMServingEngine(_EngineBase):
             self.token = jnp.asarray(
                 fin["prediction"].astype(np.int32)[:, None])
             with self.profiler.span("retirement"):
+                recs = []
                 for i in np.nonzero(active)[0]:
                     slot = self.slots[i]
                     slot.n_samples += int(spent[i])
@@ -1159,8 +1171,10 @@ class LMServingEngine(_EngineBase):
                     done = slot.n_decisions >= slot.req.max_new_tokens
                     if verdict[i] == FLAG or (verdict[i] == ACCEPT
                                               and done):
-                        self._retire(i, verdict[i], fin, extra_samples=0,
-                                     verdict_s=t_verdict)
+                        recs.append(self._retire(
+                            i, verdict[i], fin, extra_samples=0,
+                            verdict_s=t_verdict))
+                self._fold_slo(recs)
             if self.n_active == 0 and not self.queue:
                 break                       # nothing left to decode for
             # advance the pool clock: committed tokens -> next hidden

@@ -34,10 +34,12 @@ from repro.models.sar_cnn import SarCnnConfig, init_sar_cnn
 from repro.obs.alerts import AlertBus
 from repro.obs.registry import MetricsRegistry, add_alerts, add_slo, \
     quantile
-from repro.obs.slo import NULL_SLO, SLO, SloTracker, _EDGES
+from repro.obs.slo import NULL_SLO, SLO, SloTracker, _EDGES, _Hist, \
+    _VERDICTS
 from repro.obs.trace import Tracer
 from repro.serving import TriagePolicy
 from repro.serving.load import ArrivalSpec, run_open_loop
+from repro.serving.metrics import RequestRecord
 
 POLICY = TriagePolicy(conf_threshold=0.7, mi_threshold=0.05,
                       r_min=4, r_max=20)
@@ -441,3 +443,164 @@ def test_slo_hist_edges_cover_wide_range():
     assert d["overflow"] == 1
     assert sum(d["counts"]) + d["overflow"] == 3
     assert len(d["edges"]) == len(_EDGES)
+
+
+# ----------------------------------------------------------------------
+# 6. batch fold: observe_many equals the per-record fold
+# ----------------------------------------------------------------------
+def _fold_one_by_one(tracker, recs):
+    """The per-record fold as ``SloTracker.observe`` did it before the
+    batch fold: five scalar ``_Hist.observe`` calls per record."""
+    for rec in recs:
+        t = rec.verdict_latency_s
+        if math.isnan(t):
+            t = rec.latency_s
+        tracker._n += 1
+        tracker._ttv.observe(t)
+        tracker._queue.observe(rec.queue_latency_s)
+        tracker._service.observe(rec.service_latency_s)
+        name = _VERDICTS.get(int(rec.verdict), str(int(rec.verdict)))
+        tracker._by_verdict.setdefault(name, _Hist()).observe(t)
+        r = int(round(rec.n_samples / max(rec.n_decisions, 1)))
+        tracker._by_r.setdefault(r, _Hist()).observe(t)
+        for k, slo in enumerate(tracker.slos):
+            if t > slo.target_s:
+                tracker._violations[k] += 1
+        arr = rec.arrival_pc
+        if math.isnan(arr):
+            arr = rec.arrival_s
+        tracker._first_arrival = min(tracker._first_arrival, arr)
+        tracker._last_done = max(tracker._last_done, rec.done_s)
+
+
+def _tick_records(n, *, seed=0, verdicts=(0, 2), r_values=(4,),
+                  verdict_nan=0.0, scale=1e-3, sign=1.0):
+    """``n`` records shaped like one tick's retirements: arrival, then
+    admission, verdict and done each about ``scale`` seconds later
+    (earlier, with ``sign`` -1)."""
+    rng = np.random.default_rng(seed)
+    arrival = 100.0 - rng.uniform(0.0, 50 * scale, n)
+    admit = arrival + sign * rng.exponential(scale, n)
+    v_s = admit + sign * rng.exponential(scale, n)
+    done = v_s + sign * rng.exponential(scale / 10, n)
+    recs = []
+    for i in range(n):
+        r = int(rng.choice(r_values))
+        dec = int(rng.integers(1, 4))
+        recs.append(RequestRecord(
+            rid=i, verdict=int(rng.choice(verdicts)),
+            n_samples=r * dec, n_decisions=dec,
+            arrival_s=1.7e9 + arrival[i], admit_s=float(admit[i]),
+            done_s=float(done[i]), arrival_pc=float(arrival[i]),
+            verdict_s=(float("nan") if rng.random() < verdict_nan
+                       else float(v_s[i]))))
+    return recs
+
+
+def _hand_records():
+    """Negative intervals, past-the-last-edge intervals, NaN stamps and
+    records with only the wall-clock trio."""
+    nan = float("nan")
+    return [
+        # admitted "before" arrival and a verdict before admission
+        RequestRecord(rid=0, verdict=0, n_samples=4, n_decisions=1,
+                      arrival_s=0.0, admit_s=9.0, done_s=10.0,
+                      arrival_pc=10.0, verdict_s=8.0),
+        RequestRecord(rid=1, verdict=2, n_samples=8, n_decisions=1,
+                      arrival_s=5.0, admit_s=4.0, done_s=3.0),
+        # 100 s is the last edge: these go to overflow
+        RequestRecord(rid=2, verdict=1, n_samples=20, n_decisions=1,
+                      arrival_s=0.0, admit_s=150.0, done_s=400.0,
+                      arrival_pc=0.0, verdict_s=399.0),
+        RequestRecord(rid=3, verdict=0, n_samples=6, n_decisions=4,
+                      arrival_s=0.0, admit_s=100.0, done_s=250.0,
+                      verdict_s=nan),
+        # an infinite monotonic stamp falls back to the wall clock
+        RequestRecord(rid=4, verdict=7, n_samples=5, n_decisions=0,
+                      arrival_s=1.0, admit_s=1.5, done_s=2.0,
+                      arrival_pc=float("inf"), verdict_s=1.75),
+        RequestRecord(rid=5, verdict=2, n_samples=10, n_decisions=4,
+                      arrival_s=nan, admit_s=1.0, done_s=nan,
+                      verdict_s=nan),
+    ]
+
+
+FOLD_CASES = {
+    "tick": lambda: _tick_records(954),
+    "nan_verdict_stamp": lambda: _tick_records(64, seed=1,
+                                               verdict_nan=0.5),
+    "negative_intervals": lambda: _hand_records()[:2]
+    + _tick_records(16, seed=2, sign=-1.0),
+    "past_last_edge": lambda: _hand_records()[2:4]
+    + _tick_records(16, seed=3, scale=30.0),
+    "mixed_verdicts_and_r": lambda: _tick_records(
+        256, seed=4, verdicts=(0, 1, 2), r_values=(4, 8, 12, 20))
+    + _hand_records(),
+    "one": lambda: _tick_records(1, seed=5),
+    "empty": lambda: [],
+}
+
+
+@pytest.mark.parametrize("case", sorted(FOLD_CASES))
+def test_observe_many_equals_per_record_fold(case):
+    recs = FOLD_CASES[case]()
+    # 0.75 s is the hand-made record 4's time to verdict exactly
+    slos = ("1e-3:p99", "0.5:p50", "0.75:p99", "1e9:p99")
+    batch, one = SloTracker(slos), SloTracker(slos)
+    batch.observe_many(recs)
+    batch.observe_many([])
+    _fold_one_by_one(one, recs)
+    a, b = batch.snapshot(), one.snapshot()
+    assert a.keys() == b.keys()
+    if not recs:
+        assert a == {}
+        return
+
+    def same_hist(x, y):
+        assert x["counts"] == y["counts"]
+        assert x["overflow"] == y["overflow"]
+        assert x["count"] == y["count"]
+        assert x["total_s"] == pytest.approx(y["total_s"], rel=1e-12,
+                                             abs=0.0)
+
+    assert a["requests"] == b["requests"] == len(recs)
+    for key in ("time_to_verdict", "queue_wait", "service"):
+        same_hist(a[key], b[key])
+    for key in ("by_verdict", "by_r"):
+        assert list(a[key]) == list(b[key])
+        for k in a[key]:
+            same_hist(a[key][k], b[key][k])
+    assert [s["violations"] for s in a["slos"]] == \
+        [s["violations"] for s in b["slos"]]
+    assert a["span_s"] == b["span_s"] or \
+        (math.isnan(a["span_s"]) and math.isnan(b["span_s"]))
+
+
+def test_observe_is_a_batch_of_one():
+    recs = _tick_records(32, seed=6, verdicts=(0, 1, 2),
+                         r_values=(4, 20))
+    each, batch = SloTracker(("1e-3:p99",)), SloTracker(("1e-3:p99",))
+    for rec in recs:
+        each.observe(rec)
+    batch.observe_many(recs)
+    a, b = each.snapshot(), batch.snapshot()
+    assert a["time_to_verdict"]["counts"] == \
+        b["time_to_verdict"]["counts"]
+    assert a["slos"] == b["slos"]
+    assert a["by_r"].keys() == b["by_r"].keys()
+
+
+def test_engine_folds_each_retiring_tick_once(sar):
+    n = 24
+    eng = _engine(sar, slo=True)
+    for r in _stream(n):
+        eng.submit(r)
+    eng.run()
+    recs = eng.metrics.records
+    # every record of a tick carries that tick's verdict stamp
+    retiring_ticks = len({r.verdict_s for r in recs})
+    assert 1 < retiring_ticks < len(recs)
+    prof_snap = eng.profiler.snapshot()
+    assert prof_snap["slo_fold"]["count"] == retiring_ticks
+    assert prof_snap["retirement"]["count"] >= retiring_ticks
+    assert eng.slo.snapshot()["requests"] == len(recs) == n

@@ -50,6 +50,7 @@ PARENT = {
     "admit_stack": "admission", "featurize": "admission",
     "admit_enqueue": "admission",
     "round_wait": "triage_loop", "verdict_pull": "triage_loop",
+    "slo_fold": "retirement",
 }
 TREE = {"sar_tick", *PARENT}
 STAGE = {"sar_tick": "tick"}          # trace name -> profiler stage

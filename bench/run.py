@@ -1,4 +1,4 @@
-"""SAR triage serving benchmark: one cell, one run, one JSON line.
+"""Serving benchmark: one cell, one run, one JSON line.
 
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
@@ -6,16 +6,39 @@ Run from the root of a checkout on a machine with the chips the cell
 asks for.  The cell (``BENCHMARK.json`` ``workloads``) names a
 configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``, driven by ``bench/kinds/<kind>.py``);
-every metric is read by ``bench/metrics/<metric>.py``.
+every metric is read by ``bench/metrics/<metric>.py``; the configuration's
+``"system"`` key names the system under test, ``bench/systems/<system>.py``.
 
-Set-up: loads the detector's trained weights
-(``bench/weights/<config>.npz``), renders the image bank on the device
-from ``--seed``, builds the program's engine and warms its shapes.  The
-window then drives the engine's ``step`` for ``--seconds``; a compile
-inside it is an error.  After the window a
-sample of what was served is checked against the plain reference
-(``bench/check.py``).  With ``--trace 1`` the window is traced and the
-per-layer metrics are reported; otherwise the end-to-end ones.
+Set-up: the system module builds the engine, its inputs and their feed,
+and the traffic's kind warms its shapes.  The window then ticks the
+engine for ``--seconds``; a compile inside it is an error.  After the
+window the system module checks what was served against its plain
+reference.  With ``--trace 1`` the window is traced and the per-layer
+metrics are reported; otherwise the end-to-end ones.
+
+A system module is the only interface a new system implements:
+
+* ``CONTROLS``: the names ``--control`` may take for this system.
+* ``build(cell, seed, control, phases) -> (system, feed, ctx)``: the
+  engine, its inputs and whatever the check needs later (``ctx``, such as
+  the weights), all from the seed; each set-up phase's seconds go into
+  ``phases`` under a name of its own.
+* ``decisions(record) -> int``: how many decisions a retired record
+  stands for; the window's decisions are their sum over the records
+  retired in it.
+* ``compare(cell, ctx, served, feed, due, seed, *, submitted, control,
+  log) -> [(name, value, limit, "max" | "min")]``: the numbers compared,
+  each with its limit ("max": value <= limit passes).  ``served`` has
+  ``r_step`` and ``engines[*].metrics.records``; ``due`` lists the ids
+  submitted in the window; ids ``0 .. submitted - 1`` were all sent;
+  ``log`` takes one line.
+
+``system`` has ``slots``, ``submit(request)``, ``tick()``, ``pending``,
+``n_active``, ``profiler`` (``snapshot()`` of stage counts and seconds),
+``engines`` (each with ``metrics.records``, a record having ``rid``,
+``admit_s``, ``verdict_s`` and ``n_samples``), ``r_step`` and
+``devices()``.  ``feed`` has ``make()`` (the next request) and
+``next_rid``.
 
 The last line on standard output is the result; the last lines on
 standard error are the numbers compared, each beside its limit.  With
@@ -41,6 +64,7 @@ from types import SimpleNamespace  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
+SYSTEMS = BENCH / "systems"
 GRACE_S = 60.0
 
 
@@ -61,7 +85,8 @@ def load_cell(name: str, spec: dict | None = None) -> SimpleNamespace:
         raise BenchError(f"no workload {name!r} in BENCHMARK.json")
     cell = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
-    cfg = _load_json(ROOT / configs[cell["config"]]["file"])
+    cfg_file = ROOT / configs[cell["config"]]["file"]
+    cfg = _load_json(cfg_file)
     traffic = _load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
 
     def applies(m):
@@ -69,16 +94,33 @@ def load_cell(name: str, spec: dict | None = None) -> SimpleNamespace:
 
     return SimpleNamespace(
         name=name, chips=int(cell["chips"]), config=cell["config"],
-        cfg=cfg, traffic=traffic,
+        cfg=cfg, traffic=traffic, system=load_system(cfg, cfg_file),
         end_to_end=[m for m in spec["end_to_end"] if applies(m)],
         per_layer=[m for m in spec["per_layer"] if applies(m)])
 
 
-def _module(path: Path):
+def _module(path: Path, name: str | None = None):
     mod_spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_"), path)
+        name or "bench_" + path.stem.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def load_system(cfg: dict, cfg_file: Path):
+    """The module of the system ``cfg`` names, loaded once per file and
+    kept as ``bench.systems.<system>``."""
+    name = cfg.get("system")
+    if not name:
+        raise BenchError(f"{cfg_file} names no system (its \"system\" key)")
+    path = SYSTEMS / f"{name}.py"
+    if not path.is_file():
+        raise BenchError(f"{cfg_file} names system {name!r}, but {path} "
+                         "does not exist")
+    key = f"bench.systems.{name}"
+    mod = sys.modules.get(key)
+    if mod is None or Path(mod.__file__).resolve() != path.resolve():
+        mod = sys.modules[key] = _module(path, key)
     return mod
 
 
@@ -95,27 +137,6 @@ def peaks(device_kind: str) -> dict:
     if device_kind not in table:
         raise BenchError(f"device kind {device_kind!r} not in bench/peaks.json")
     return table[device_kind]
-
-
-class Feed:
-    """Requests over the image bank, in an order drawn from the seed."""
-
-    def __init__(self, bank, seed: int):
-        import numpy as np
-        from repro.serving.engine import Request
-        self._request = Request
-        self.bank = bank
-        self.order = np.random.default_rng(
-            [seed & 0xFFFFFFFF, seed >> 32, 0xFEED]).permutation(len(bank))
-        self.next_rid = 0
-
-    def image_of(self, rid: int):
-        return self.bank[self.order[rid % len(self.bank)]]
-
-    def make(self):
-        rid = self.next_rid
-        self.next_rid += 1
-        return self._request(rid=rid, payload=self.image_of(rid))
 
 
 def _memory_peak(devices) -> int:
@@ -158,8 +179,10 @@ def run_cell(cell: SimpleNamespace, seed: int, seconds: float, trace: bool,
              *, require_tpu: bool = True, control: str | None = None,
              log=print) -> dict:
     """One run of ``cell``; returns the result object."""
+    if control is not None and control not in cell.system.CONTROLS:
+        raise BenchError(f"control {control!r} is not one of "
+                         f"{cell.system.__name__}'s {cell.system.CONTROLS}")
     import jax
-    import numpy as np
     devices = jax.devices()
     if require_tpu and devices[0].platform != "tpu":
         raise BenchError(f"no TPU: JAX platform is {devices[0].platform!r}")
@@ -170,29 +193,11 @@ def run_cell(cell: SimpleNamespace, seed: int, seconds: float, trace: bool,
     phases = {"start": time.perf_counter() - T_START}
     from repro.launch.compile_cache import enable_compile_cache
     from repro.obs import prof
-
-    from bench import check, sard
-    from bench.system import System
     enable_compile_cache()
-    cfg, traffic = cell.cfg, cell.traffic
-
-    t = time.perf_counter()
-    params = sard.load_params(sard.WEIGHTS / f"{cell.config}.npz",
-                              sard.recipe_of(cfg))
-    phases["weights"] = time.perf_counter() - t
-    t = time.perf_counter()
-    bank_spec = traffic["bank"]
-    bank = np.asarray(sard.image_bank(
-        jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
-                           seed >> 32),
-        bank_spec["images"], cfg["model"]["image_size"],
-        bank_spec["fog_share"], bank_spec["fog_severity"]))
-    phases["bank"] = time.perf_counter() - t
-    t = time.perf_counter()
-    system = System(cfg, params, fused=control != "unfused")
-    feed = Feed(bank, seed)
+    traffic = cell.traffic
     kind = driver(traffic["kind"])
-    phases["build"] = time.perf_counter() - t
+
+    system, feed, ctx = cell.system.build(cell, seed, control, phases)
     t = time.perf_counter()
     kind.warmup(system, feed, traffic)
     phases["warmup"] = time.perf_counter() - t
@@ -237,9 +242,10 @@ def run_cell(cell: SimpleNamespace, seed: int, seconds: float, trace: bool,
     run = SimpleNamespace(
         cell=cell, seed=seed, setup_s=setup_s, t0=t0, t1=t1,
         window_s=t1 - t0, ticks=win["ticks"], stages=stages,
-        decisions=sum(1 for r in records if t0 <= r.verdict_s <= t1),
+        decisions=sum(cell.system.decisions(r) for r in records
+                      if t0 <= r.verdict_s <= t1),
         due=win["due"], records=by_rid,
-        chips=len(system.devices()), peaks=device_peaks, cfg=cfg,
+        chips=len(system.devices()), peaks=device_peaks, cfg=cell.cfg,
         r_step=system.r_step, trace=None)
     if trace:
         run.trace = tr.reduce(tr.collect(trace_dir))
@@ -251,13 +257,15 @@ def run_cell(cell: SimpleNamespace, seed: int, seconds: float, trace: bool,
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
+    # what was served, apart from the engine, whose device state is freed
+    # before the reference runs
     served = SimpleNamespace(r_step=system.r_step, engines=[
         SimpleNamespace(metrics=SimpleNamespace(records=e.metrics.records))
         for e in system.engines])
     del system
-    compared = check.compare(cfg, params, served, feed.image_of, due, seed,
-                             submitted=feed.next_rid, control=control,
-                             log=lambda m: log(m, file=sys.stderr))
+    compared = cell.system.compare(
+        cell, ctx, served, feed, due, seed, submitted=feed.next_rid,
+        control=control, log=lambda m: log(m, file=sys.stderr))
     correct = all((v <= lim) if kind_ == "max" else (v >= lim)
                   for _, v, lim, kind_ in compared)
     checks = {name: {"value": v, "limit": lim,
@@ -286,9 +294,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--control", choices=("unfused", "reference_bf16"),
-                    help="serve the precision control instead of the "
-                         "configuration (for measuring limits)")
+    ap.add_argument("--control",
+                    help="serve one of the system's controls (its module's "
+                         "CONTROLS) instead of the configuration (for "
+                         "measuring limits)")
     args = ap.parse_args(argv)
     if not (ROOT / "src" / "repro").is_dir():
         print(f"bench: no program under {ROOT / 'src'}", file=sys.stderr)
